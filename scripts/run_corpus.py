@@ -14,7 +14,10 @@ safe alongside JAX).  Workers receive entry *names* and return plain
 result rows, so nothing unpicklable crosses the process boundary, and
 the table is printed in deterministic entry order regardless of which
 worker finishes first: the output is byte-identical to a sequential run
-apart from the wall_s column.
+apart from the wall_s column.  An accelerator belongs to one process at a
+time, so the workers run with ``JAX_PLATFORMS=cpu``, and ``--jobs > 1``
+is refused together with ``--distance-backend jax|pallas`` (the device
+lanes run in this one process, on whatever device JAX finds).
 
 Recovery-backend entries (``--backend recovery``) run the closed
 mitigation loop end-to-end (docs/mitigation.md): live per-step verdicts
@@ -187,6 +190,12 @@ def main(argv=None) -> int:
     if args.jobs < 1:
         print("--jobs must be >= 1", file=sys.stderr)
         return 2
+    if args.jobs > 1 and args.distance_backend in ("jax", "pallas"):
+        print(f"--jobs {args.jobs} cannot run --distance-backend "
+              f"{args.distance_backend}: pool workers run on the CPU, and an "
+              f"accelerator takes one process; use --jobs 1",
+              file=sys.stderr)
+        return 2
 
     from repro.scenarios import select_entries
     try:
@@ -209,6 +218,12 @@ def main(argv=None) -> int:
         import multiprocessing as mp
         from concurrent.futures import ProcessPoolExecutor
         ctx = mp.get_context("spawn")   # fork is unsafe alongside JAX
+        # Spawned workers inherit this environment: each one runs JAX on
+        # the CPU and none claims an accelerator.  This process has not
+        # touched a JAX backend, and only prints the workers' rows.
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        print(f"pool: {min(args.jobs, len(names))} workers, "
+              f"JAX_PLATFORMS=cpu", file=sys.stderr)
         with ProcessPoolExecutor(max_workers=min(args.jobs, len(names)),
                                  mp_context=ctx) as pool:
             futures = [pool.submit(run_one, n, args.seed,
@@ -241,4 +256,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

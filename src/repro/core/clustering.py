@@ -173,7 +173,8 @@ class _JaxDistanceBackend:
 
         @jax.jit
         def _rows(W, sq, idx):
-            G = W[idx] @ W.T
+            # HIGHEST: see the Pallas kernel (repro.kernels.distance).
+            G = jnp.dot(W[idx], W.T, precision=jax.lax.Precision.HIGHEST)
             return jnp.maximum(sq[idx][:, None] + sq[None, :] - 2.0 * G,
                                0.0)
 
@@ -190,10 +191,13 @@ class _JaxDistanceBackend:
     def device_rows(self, handle, idx: Sequence[int]):
         Wd, sqd = handle
         ii = np.asarray(idx, dtype=np.int32)
-        # Pad the seed count to a power of two so jit traces stay bounded
-        # (duplicated seeds are sliced back off).
+        # Pad the seed count to a power of two >= 8 so jit traces stay
+        # bounded (duplicated seeds are sliced back off) and a row never
+        # comes from a gemv: XLA's gemv and gemm round differently, so a
+        # one-seed fetch would not bitwise-match the same row fetched in a
+        # batch, and the device row cache needs that.
         k = int(ii.size)
-        kp = 1 << max(0, (k - 1).bit_length())
+        kp = 1 << max(3, (k - 1).bit_length())
         pad = np.full(kp, ii[0], dtype=np.int32)
         pad[:k] = ii
         return self._rows(Wd, sqd, pad)[:k]
@@ -299,12 +303,19 @@ def _greedy_cluster(m: int,
                     sq: np.ndarray,
                     threshold: Optional[float],
                     threshold_frac: float,
-                    count_threshold: int) -> ClusterResult:
+                    count_threshold: int,
+                    zero: Optional[np.ndarray] = None) -> ClusterResult:
     """The simplified-OPTICS greedy pass over lazily materialized D² rows.
 
     ``row_of(p)`` returns the squared distances from point p to all points
     under the *current* matrix; only rows of seed points are ever computed,
     so a clustering costs O(#clusters · m) beyond the cached state.
+
+    ``zero`` (optional, (m,) bool) marks the points whose vector is
+    exactly zero.  A zero seed's norm is then exactly 0, and its
+    neighbours within a zero threshold are exactly the zero points — pass
+    it whenever ``sq`` and the rows come from toggle deltas, which leave
+    roundoff residue where they should cancel to 0.
     """
     labels = np.full(m, -1, dtype=np.int64)
     n_clusters = 0
@@ -314,13 +325,20 @@ def _greedy_cluster(m: int,
         if unassigned.size == 0:
             break
         p = int(unassigned[0])
-        thr = threshold if threshold is not None else threshold_frac * \
-            math.sqrt(max(float(sq[p]), 0.0))
+        zero_seed = zero is not None and bool(zero[p])
+        if threshold is not None:
+            thr = threshold
+        else:
+            thr = 0.0 if zero_seed else threshold_frac * \
+                math.sqrt(max(float(sq[p]), 0.0))
         used_threshold = max(used_threshold, thr)
         # `<=` (not the paper's strict `<`) so identical vectors cluster
         # together even when the seed norm — and hence the threshold — is 0.
         row = row_of(p)
-        cand = unassigned[row[unassigned] <= thr * thr]
+        near = row[unassigned] <= thr * thr
+        if zero_seed:
+            near = zero[unassigned] | (near & (thr > 0))
+        cand = unassigned[near]
         cand = cand[cand != p]
         if cand.size >= count_threshold:
             labels[p] = n_clusters
@@ -431,23 +449,28 @@ class IncrementalClusterState:
         self._W0 = self._W
         self._sq0 = np.einsum("ij,ij->i", self._W0, self._W0)
         self._sq = self._sq0
+        # Exact count of nonzero entries per row of the current matrix: a
+        # row that toggles turn to zero keeps a squared norm (and D² row)
+        # of roundoff residue, its count is exactly 0 (see _greedy_cluster).
+        self._nnz = np.count_nonzero(self._W0, axis=1)
         self._backend = get_distance_backend(backend)
         self._handle = self._backend.prepare(self._W0, self._sq0)
         self._rows: "OrderedDict[int, np.ndarray]" = OrderedDict()
         self._row_cache = max(int(row_cache), 1)
         # Base-row fetch accounting (host LRU + device row cache share
         # it): backend calls, total rows fetched, per-seed fetch counts —
-        # the dedup contract tests/test_device_lockstep.py pins.
+        # the dedup contract tests/test_device_lockstep.py pins — and the
+        # calls made by the device lockstep path alone.
         self.fetch_stats: Dict[str, object] = {
-            "calls": 0, "rows": 0, "per_seed": {}}
+            "calls": 0, "rows": 0, "per_seed": {}, "device_calls": 0}
         self._device = None   # DeviceLockstep | False (probed) | None
-        # stack of (cols, old values, installed values, saved sq) — sq is
-        # replaced, not updated in place, so popping restores it
-        # bit-for-bit; the installed values (not the live matrix) drive the
-        # per-level D² deltas so that toggles of overlapping columns
-        # telescope correctly.
+        # stack of (cols, old values, installed values, saved sq, saved
+        # nnz) — sq and nnz are replaced, not updated in place, so popping
+        # restores them bit-for-bit; the installed values (not the live
+        # matrix) drive the per-level D² deltas so that toggles of
+        # overlapping columns telescope correctly.
         self._stack: List[Tuple[List[int], np.ndarray, np.ndarray,
-                                np.ndarray]] = []
+                                np.ndarray, np.ndarray]] = []
 
     @property
     def matrix(self) -> np.ndarray:
@@ -464,7 +487,7 @@ class IncrementalClusterState:
         copy at all."""
         if self._W is self._W0 and self._stack:
             self._W = self._W0.copy()
-            for cols, _old, new, _sq in self._stack:
+            for cols, _old, new, _sq, _nnz in self._stack:
                 self._W[:, cols] = new
         return self._W
 
@@ -486,19 +509,20 @@ class IncrementalClusterState:
             self._materialize()
         old = self._W[:, cols].copy()
         new = _expand_column_values(values, self._m, len(cols))
-        saved_sq = self._sq
+        saved_sq, saved_nnz = self._sq, self._nnz
         self._sq = saved_sq - np.einsum("ij,ij->i", old, old) \
             + np.einsum("ij,ij->i", new, new)
+        self._nnz = saved_nnz - np.count_nonzero(old, axis=1) \
+            + np.count_nonzero(new, axis=1)
         if self._W is not self._W0:
             self._W[:, cols] = new
-        self._stack.append((cols, old, new, saved_sq))
+        self._stack.append((cols, old, new, saved_sq, saved_nnz))
 
     def pop(self) -> None:
         """Revert the most recent :meth:`push` exactly."""
-        cols, old, _new, saved_sq = self._stack.pop()
+        cols, old, _new, self._sq, self._nnz = self._stack.pop()
         if self._W is not self._W0:
             self._W[:, cols] = old
-        self._sq = saved_sq
 
     def _ensure_base_rows(self, ps: Sequence[int]) -> None:
         """Fetch (in one stacked backend call) and LRU-cache the base D²
@@ -537,7 +561,7 @@ class IncrementalClusterState:
         if not self._stack:
             return row
         row = row.copy()
-        for cols, old, new, _ in self._stack:
+        for cols, old, new, _sq, _nnz in self._stack:
             dn = new - new[p]
             do = old - old[p]
             row += np.einsum("ij,ij->i", dn, dn) \
@@ -589,7 +613,7 @@ class IncrementalClusterState:
                 return self._device_results(dev.cluster_batch([[]]))[0]
         return _greedy_cluster(self._m, self._row, self._sq,
                                self._threshold, self._threshold_frac,
-                               self._count_threshold)
+                               self._count_threshold, zero=self._nnz == 0)
 
     def cluster_batch(self, toggles: Sequence[Tuple[Sequence[int], object]]
                       ) -> List[ClusterResult]:
@@ -675,11 +699,16 @@ class IncrementalClusterState:
         difference near zero could flip a partition on float data.  The
         stacking into the (trials, m) tensor happens after, for the
         vectorized neighbourhood/assignment phase (exact integer and
-        comparison ops)."""
+        comparison ops).
+
+        A trial whose toggle turns the seed's row exactly zero takes the
+        zero-seed rule of :func:`_greedy_cluster`, from exact per-row
+        nonzero counts."""
         m = row_p.shape[0]
         need_sq = self._threshold is None       # thresholds from seed norms
         rows = np.empty((len(ts), m))
         sqp = np.empty(len(ts))
+        zero_seed: Dict[int, np.ndarray] = {}   # chunk index -> zero rows
         for i, t in enumerate(ts):
             old = self._W[:, cols_l[t]].copy()
             do = old - old[p]
@@ -693,11 +722,17 @@ class IncrementalClusterState:
                 dn = new - new[p]
                 delta = np.einsum("ij,ij->i", dn, dn) - db
             rows[i] = row_p + delta
+            nz_new = 0 if new is None else np.count_nonzero(new[p])
+            if self._nnz[p] - np.count_nonzero(old[p]) + nz_new == 0:
+                nnz = self._nnz - np.count_nonzero(old, axis=1)
+                if new is not None:
+                    nnz = nnz + np.count_nonzero(new, axis=1)
+                zero_seed[i] = nnz == 0
             if need_sq:
                 sq_t = self._sq - np.einsum("ij,ij->i", old, old)
                 if new is not None:
                     sq_t = sq_t + np.einsum("ij,ij->i", new, new)
-                sqp[i] = sq_t[p]
+                sqp[i] = 0.0 if i in zero_seed else sq_t[p]
         np.maximum(rows, 0.0, out=rows)
         ts_arr = np.asarray(ts, dtype=np.int64)
         if self._threshold is not None:
@@ -709,6 +744,9 @@ class IncrementalClusterState:
         used_thr[ts_arr] = np.maximum(used_thr[ts_arr], thr)
         sub = labels[ts_arr]                           # (k, m) copy
         cand = (sub < 0) & (rows <= (thr * thr)[:, None])
+        for i, zero in zero_seed.items():
+            near = zero | cand[i] if thr[i] > 0 else zero
+            cand[i] = (sub[i] < 0) & near
         cand[:, p] = False
         counts = cand.sum(axis=1)
         newlab = n_clusters[ts_arr]
@@ -763,8 +801,49 @@ def dissimilarity_severity(result: ClusterResult, vectors: np.ndarray) -> float:
 
 # Jitted Lloyd iterations (device k-means variant), cached at module
 # level so every kmeans_1d(backend="jax"/"pallas") call shares one trace
-# per (n, k, dtype).
+# per (n, k, dtype); built on first use, so the numpy default never
+# imports jax.
 _KMEANS_JIT: Dict[str, object] = {}
+
+
+def _lloyd_jit():
+    """The jitted float64 Lloyd ``lax.while_loop`` of
+    :func:`_kmeans_lloyd_jax`: ``fn(x, centroids, *, n_iter)``."""
+    fn = _KMEANS_JIT.get("lloyd")
+    if fn is not None:
+        return fn
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, static_argnames=("n_iter",))
+    def fn(xv, cent0, *, n_iter):
+        k = cent0.shape[0]
+
+        def cond(s):
+            it, done, _, _ = s
+            return (it < n_iter) & (~done)
+
+        def body(s):
+            it, _, cent, _ = s
+            d = jnp.abs(xv[:, None] - cent[None, :])
+            lab = jnp.argmin(d, axis=1).astype(jnp.int64)
+            counts = jnp.zeros(k, xv.dtype).at[lab].add(1.0)
+            sums = jnp.zeros(k, xv.dtype).at[lab].add(xv)
+            # Empty clusters keep their previous centroid.
+            new = jnp.where(counts > 0,
+                            sums / jnp.maximum(counts, 1.0), cent)
+            done = jnp.allclose(new, cent)
+            return (it + 1, done, jnp.where(done, cent, new), lab)
+
+        lab0 = jnp.zeros(xv.shape[0], dtype=jnp.int64)
+        _, _, cent, lab = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), jnp.bool_(False), cent0, lab0))
+        return cent, lab
+
+    _KMEANS_JIT["lloyd"] = fn
+    return fn
 
 
 def _kmeans_lloyd_jax(x: np.ndarray, centroids: np.ndarray,
@@ -775,42 +854,11 @@ def _kmeans_lloyd_jax(x: np.ndarray, centroids: np.ndarray,
     Mirrors the numpy loop's semantics exactly: labels are the argmin
     against the centroids *entering* the convergence iteration, and the
     converged centroids keep their pre-update values."""
-    import functools
-
     import jax
     import jax.numpy as jnp
 
-    fn = _KMEANS_JIT.get("lloyd")
-    if fn is None:
-        @functools.partial(jax.jit, static_argnames=("n_iter",))
-        def fn(xv, cent0, *, n_iter):
-            k = cent0.shape[0]
-
-            def cond(s):
-                it, done, _, _ = s
-                return (it < n_iter) & (~done)
-
-            def body(s):
-                it, _, cent, _ = s
-                d = jnp.abs(xv[:, None] - cent[None, :])
-                lab = jnp.argmin(d, axis=1).astype(jnp.int64)
-                counts = jnp.zeros(k, xv.dtype).at[lab].add(1.0)
-                sums = jnp.zeros(k, xv.dtype).at[lab].add(xv)
-                # Empty clusters keep their previous centroid.
-                new = jnp.where(counts > 0,
-                                sums / jnp.maximum(counts, 1.0), cent)
-                done = jnp.allclose(new, cent)
-                return (it + 1, done, jnp.where(done, cent, new), lab)
-
-            lab0 = jnp.zeros(xv.shape[0], dtype=jnp.int64)
-            _, _, cent, lab = jax.lax.while_loop(
-                cond, body, (jnp.int32(0), jnp.bool_(False), cent0, lab0))
-            return cent, lab
-
-        _KMEANS_JIT["lloyd"] = fn
-
-    from jax.experimental import enable_x64
-    with enable_x64():
+    fn = _lloyd_jit()
+    with jax.enable_x64(True):
         cent, lab = fn(jnp.asarray(x), jnp.asarray(centroids),
                        n_iter=int(n_iter))
         return np.asarray(cent), np.asarray(lab)

@@ -49,28 +49,32 @@ import jax.numpy as jnp
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
-def _prep(Wd, Ad, cols, *, n):
+def _prep(Wd, Ad, nnz, cols, *, n):
     """Per-trial toggled-column gathers.
 
     Wd/Ad : (m, n) points and their elementwise squares.
+    nnz   : (m,) int32 count of nonzero entries per point.
     cols  : (nt, w) int32 toggled-column ids (sentinel ``n`` pads).
-    Returns ``Wc`` (nt, w, m) toggled values and ``af`` (nt, m), the
+    Returns ``Wc`` (nt, w, m) toggled values, ``af`` (nt, m), the
     per-point masked squared mass ``sum_j W[q, j]^2`` over each trial's
-    toggled columns.  Sentinel slots gather a real column and are masked
-    to zero — column gathers touch only O(nt·w·m) values, so no (n, m)
-    transpose or padded copy of the full matrix is ever built.
+    toggled columns, and ``zero`` (nt, m), the points each trial's
+    zero-toggle leaves exactly zero.  Sentinel slots gather a real column
+    and are masked to zero — column gathers touch only O(nt·w·m) values,
+    so no (n, m) transpose or padded copy of the full matrix is ever
+    built.
     """
     cid = jnp.minimum(cols, n - 1)
     valid = (cols < n).astype(Wd.dtype)                 # (nt, w)
     Wc = jnp.transpose(Wd[:, cid], (1, 2, 0)) * valid[:, :, None]
     af = (Ad[:, cid] * valid[None, :, :]).sum(axis=2).T
-    return Wc, af
+    zero = nnz[None, :] == (Wc != 0).sum(axis=1)
+    return Wc, af, zero
 
 
 @functools.partial(jax.jit, static_argnames=("frac", "fixed", "ct"),
-                   donate_argnums=(7, 8, 9))
-def _round(Wc, af, sq, rcache, sidx, p, active, labels, ncl, used_thr,
-           *, frac, fixed, ct):
+                   donate_argnums=(8, 9, 10))
+def _round(Wc, af, zero, sq, rcache, sidx, p, active, labels, ncl,
+           used_thr, *, frac, fixed, ct):
     """One lockstep greedy round for every active trial — the exact
     device mirror of the host ``_batch_round`` semantics.
 
@@ -81,6 +85,10 @@ def _round(Wc, af, sq, rcache, sidx, p, active, labels, ncl, used_thr,
     threshold/candidacy/assignment phase *and* the next round's seed
     selection into a single dispatch (the driver pulls only the 2·nt
     scalars of next seeds/activity per round).
+    A seed that the toggle leaves exactly zero (``zero``) takes the
+    zero-seed rule of the host ``_greedy_cluster``: norm 0, and exactly
+    the zero points within a zero threshold — the residue of the deltas
+    decides nothing.
     ``labels``/``ncl``/``used_thr`` are donated: each round writes the
     next round's state into the buffers of the last.
     """
@@ -92,13 +100,17 @@ def _round(Wc, af, sq, rcache, sidx, p, active, labels, ncl, used_thr,
     # No zero clamp: candidacy compares against thr² >= 0, so negative
     # roundoff residue decides identically to the clamped row.
     rows = R - (af + afp - 2.0 * b)
+    zp = jnp.take_along_axis(zero, p[:, None], axis=1)     # (nt, 1)
     if fixed is None:
-        sqp = jnp.maximum(sq[p] - afp[:, 0], 0.0)
+        sqp = jnp.where(zp[:, 0], 0.0,
+                        jnp.maximum(sq[p] - afp[:, 0], 0.0))
         thr = frac * jnp.sqrt(sqp)
     else:
         thr = jnp.full((nt,), fixed, rows.dtype)
     used_thr = jnp.where(active, jnp.maximum(used_thr, thr), used_thr)
-    cand = (labels < 0) & (rows <= (thr * thr)[:, None])
+    near = rows <= (thr * thr)[:, None]
+    near = jnp.where(zp, zero | (near & (thr > 0)[:, None]), near)
+    cand = (labels < 0) & near
     # cand includes the seed itself on every active trial (its own row
     # entry is exactly 0), so the neighbour count is the sum minus one —
     # cheaper than scattering the seed column out of cand.
@@ -125,6 +137,7 @@ class DeviceLockstep:
         self._m, self._n = int(Wd.shape[0]), int(Wd.shape[1])
         self._Wd = Wd
         self._Ad = Wd * Wd
+        self._nnz = jnp.count_nonzero(Wd, axis=1).astype(jnp.int32)
         self._sqd = sqd
         self._fixed = None if threshold is None else float(threshold)
         self._frac = float(threshold_frac)
@@ -148,6 +161,7 @@ class DeviceLockstep:
             self._handle, np.asarray(missing, dtype=np.int32))
         st = self._stats
         st["calls"] += 1
+        st["device_calls"] += 1
         st["rows"] += len(missing)
         for q in missing:
             st["per_seed"][q] = st["per_seed"].get(q, 0) + 1
@@ -187,7 +201,8 @@ class DeviceLockstep:
         for t, cl in enumerate(cols_l):
             cols[t, :len(cl)] = cl
         cols[nt:] = cols[0]
-        Wc, af = _prep(self._Wd, self._Ad, jnp.asarray(cols), n=self._n)
+        Wc, af, zero = _prep(self._Wd, self._Ad, self._nnz,
+                             jnp.asarray(cols), n=self._n)
         labels = jnp.full((ntp, m), -1, jnp.int32)
         ncl = jnp.zeros((ntp,), jnp.int32)
         used_thr = jnp.full((ntp,), -1.0, jnp.float32)
@@ -203,7 +218,7 @@ class DeviceLockstep:
             for t in np.nonzero(act_h)[0]:
                 sidx[t] = self._slot[int(p_h[t])]
             labels, ncl, used_thr, p, active = _round(
-                Wc, af, self._sqd, self._rcache, jnp.asarray(sidx), p,
+                Wc, af, zero, self._sqd, self._rcache, jnp.asarray(sidx), p,
                 active, labels, ncl, used_thr,
                 frac=self._frac, fixed=self._fixed, ct=self._ct)
             p_h = np.asarray(p)

@@ -39,6 +39,10 @@ class DissimilarityReport:
     cccrs: List[int]
     severity: float
     composite_s: int = 1  # >1 when composite regions were needed
+    # The clustering state's row-fetch accounting
+    # (IncrementalClusterState.fetch_stats); None on the cluster_fn path.
+    fetch_stats: Optional[Dict[str, object]] = dataclasses.field(
+        default=None, compare=False, repr=False)
 
 
 @dataclasses.dataclass
@@ -205,7 +209,9 @@ def find_dissimilarity_bottlenecks(
     ev = _TrialEvaluator(state, T, zeroed0)
     baseline = ev.cluster()
     if baseline.n_clusters == 1:
-        return DissimilarityReport(False, baseline, [], [], 0.0)
+        return DissimilarityReport(
+            False, baseline, [], [], 0.0,
+            fetch_stats=getattr(state, "fetch_stats", None))
     # Only reported on the bottleneck path, so only computed here.
     severity = dissimilarity_severity(baseline, work)
 
@@ -265,7 +271,9 @@ def find_dissimilarity_bottlenecks(
         s -= 1
 
     return DissimilarityReport(True, baseline, sorted(set(ccrs)),
-                               sorted(set(cccrs)), severity, s)
+                               sorted(set(cccrs)), severity, s,
+                               fetch_stats=getattr(state, "fetch_stats",
+                                                   None))
 
 
 def time_share_weighting(tree: RegionTree, wall: np.ndarray,

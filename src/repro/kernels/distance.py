@@ -45,8 +45,14 @@ def _round_up(x: int, mult: int) -> int:
 
 
 def _kernel(ws_ref, sqs_ref, w_ref, sq_ref, o_ref):
+    # HIGHEST: at default precision the TPU's matrix unit rounds f32
+    # inputs to bf16, and the Gram identity's cancellation turns that into
+    # D² errors of about a tenth of the clustering threshold² — enough to
+    # move points near it across (tests/test_device_lockstep.py pins the
+    # error).
     g = jnp.dot(ws_ref[...], w_ref[...].T,
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
     d = sqs_ref[...] + sq_ref[...] - 2.0 * g
     o_ref[...] = jnp.maximum(d, 0.0)
 

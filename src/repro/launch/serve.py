@@ -21,17 +21,19 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+from typing import List, Tuple
 
 import jax
 
 from repro.configs import get_arch
 from repro.models import build
-from repro.scenarios.traffic import TrafficConfig, generate_traffic
+from repro.scenarios.traffic import (Request, TrafficConfig,
+                                     generate_traffic)
 from repro.serve import ServeConfig, ServeEngine
 from repro.serve.runtime import JitBackend, supports_chunk
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="st-100m")
     ap.add_argument("--smoke", action="store_true")
@@ -57,12 +59,22 @@ def main(argv=None) -> int:
     ap.add_argument("--spool-dir", default=None, metavar="DIR",
                     help="stream per-step traces to a TraceSpool "
                          "(live-tailable via scripts/watch_train.py)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def serve(args: argparse.Namespace
+          ) -> Tuple[ServeEngine, JitBackend, List[Request]]:
+    """Build the model from a seed, generate the traffic and drain it
+    through the engine; returns the finished engine, its backend and the
+    traffic it served."""
     entry = get_arch(args.arch)
     cfg = entry.smoke if args.smoke else entry.full
     api = build(cfg)
-    params, _ = api.init(jax.random.key(args.seed))
+    # Jitted, each weight's float32 random draw fuses into its cast to
+    # the parameter dtype; eager, it would sit beside it in device memory
+    # (the largest stacked weight at full width is 3.8 GB in float32).
+    params = jax.jit(lambda key: api.init(key)[0])(
+        jax.random.key(args.seed))
 
     chunk = args.chunk if supports_chunk(cfg) else 1
     chunk = min(chunk, args.prompt_len)
@@ -91,7 +103,12 @@ def main(argv=None) -> int:
                     trace_path=args.trace, trace_spool_dir=args.spool_dir),
         traffic, backend)
     engine.run()
+    return engine, backend, traffic
 
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    engine, backend, _ = serve(args)
     for rid in sorted(backend.outputs):
         print(f"request {rid}: {backend.outputs[rid]}")
     tp = engine.throughput()
@@ -115,4 +132,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

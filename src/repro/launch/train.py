@@ -4,9 +4,9 @@ Smoke-scale on CPU:
   PYTHONPATH=src python -m repro.launch.train --arch st-100m --smoke \
       --steps 20 --batch 4 --seq 64
 
-Production (TPU pod): same entry point with --mesh data×model taken from
-the real device set; on this CPU container multi-device runs use
-XLA_FLAGS=--xla_force_host_platform_device_count=N.
+The Trainer runs on the default device (one chip, or the CPU with
+JAX_PLATFORMS=cpu); it builds no mesh, and the traced shards are
+emulated on that one device.
 """
 from __future__ import annotations
 
@@ -65,4 +65,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -1,4 +1,4 @@
-"""Gradient compression for slow inter-pod links (DESIGN.md §6).
+"""Gradient compression for slow inter-pod links.
 
 Int8 stochastic-free symmetric quantization with per-leaf fp32 scales.
 ``compressed_psum`` wraps the cross-pod gradient all-reduce in a shard_map
@@ -14,28 +14,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # newer jax exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect
-
-# The replication-check kwarg was renamed check_rep -> check_vma across jax
-# releases; pick whichever this jax understands.
-_CHECK_KWARG = ("check_vma"
-                if "check_vma" in inspect.signature(_shard_map).parameters
-                else "check_rep")
-
-
-def shard_map(f, *args, **kwargs):
-    if "check_vma" in kwargs and "check_rep" in kwargs:
-        raise TypeError("pass only one of check_vma / check_rep")
-    for alias in ("check_vma", "check_rep"):
-        if alias in kwargs and alias != _CHECK_KWARG:
-            kwargs[_CHECK_KWARG] = kwargs.pop(alias)
-    return _shard_map(f, *args, **kwargs)
 
 
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -91,6 +69,7 @@ def pod_compressed_allreduce(mesh: Mesh, grads_stacked, axis: str = "pod"):
         return jax.tree.map(lambda g: g[0], grads_stacked)
     in_spec = jax.tree.map(lambda _: P(axis), grads_stacked)
     out_spec = jax.tree.map(lambda _: P(), grads_stacked)
-    fn = shard_map(partial(compressed_psum_fn, axis=axis), mesh=mesh,
-                   in_specs=(in_spec,), out_specs=out_spec, check_vma=False)
+    fn = jax.shard_map(partial(compressed_psum_fn, axis=axis), mesh=mesh,
+                       in_specs=(in_spec,), out_specs=out_spec,
+                       check_vma=False)
     return fn(grads_stacked)

@@ -84,14 +84,19 @@ class JitBackend:
                      for p in (PREFILL, DECODE, KV_APPEND, SAMPLE)}
         self._decode = jax.jit(
             lambda p, s, t, pos: api.decode_step(p, s, t, pos))
+        # The sampler also reports whether the logits row it sampled was
+        # finite: argmax of a row holding NaN still returns a token.
         self._sample = jax.jit(
-            lambda logits: jnp.argmax(logits[:, -1:], axis=-1)
-            .astype(jnp.int32))
+            lambda logits: (jnp.argmax(logits[:, -1:], axis=-1)
+                            .astype(jnp.int32),
+                            jnp.isfinite(logits[:, -1]).all()))
         # Per-lane decode state.
         self._state: List[Any] = [None] * lanes
         self._pending_logits: List[Any] = [None] * lanes
         self._prompt: List[Optional[np.ndarray]] = [None] * lanes
         self.outputs: Dict[int, List[int]] = {}
+        # Sampled logits rows that held a NaN or an infinity.
+        self.nonfinite_samples = 0
         # (flops, bytes) per decode-call token count, from HLO cost
         # analysis of the compiled executable for that shape.
         self._decode_costs: Dict[int, Tuple[float, float]] = {}
@@ -139,8 +144,7 @@ class JitBackend:
             logits, _ = self._decode(self.params, state, toks, pos)
             self._costs_for(toks, pos, state)
         if logits is not None:
-            tok = self._sample(logits)
-            tok.block_until_ready()
+            jax.block_until_ready(self._sample(logits))
             if self._sample_cost is None:
                 compiled = self._sample.lower(logits).compile()
                 self._sample_cost = cost_analysis_of(compiled)
@@ -184,11 +188,12 @@ class JitBackend:
             if ev.decode_tokens:
                 # Sample the pending logits (its own timed region), then
                 # feed the sampled token to produce the next logits.
-                tok, dw, dc = self._timed(self._sample,
-                                          self._pending_logits[lane])
+                (tok, finite), dw, dc = self._timed(
+                    self._sample, self._pending_logits[lane])
                 sfl, sby = self._sample_cost or (0.0, 0.0)
                 self._write(tr, SAMPLE, lane, dw, dc, sfl, sby)
                 self.outputs[req.rid].append(int(tok[0, 0]))
+                self.nonfinite_samples += not bool(finite)
                 pos = jnp.int32(ev.decode_pos)
                 fl, by = self._costs_for(tok, pos, self._state[lane])
                 (logits, new_state), dw, dc = self._timed(

@@ -313,9 +313,10 @@ class OnlineAnalyzer:
         0 when reassembled from a spool); ``start``/``stop`` are the
         absolute run-step labels the log reports.
 
-        Degrades instead of crashing: non-finite samples or an analyzer
-        exception yield a :class:`DegradedWindow` so a single bad window
-        cannot take down a live watcher mid-run."""
+        Non-finite samples yield a :class:`DegradedWindow`: bad data is
+        reported, not analyzed.  An exception from the analyzer itself
+        (a backend that fails to import or compile, a bug) propagates —
+        it is a fault of the watcher, not of the window."""
         idx = len(self.log.windows)
         w0, w1 = window
         bad = sorted(k for k, v in trace.data.items()
@@ -325,16 +326,9 @@ class OnlineAnalyzer:
                 index=idx, start=start, stop=stop,
                 reason="non-finite samples", detail={"metrics": bad})
         else:
-            try:
-                res = analyzer.analyze_trace(trace, window=window)
-            except Exception as e:
-                wv = DegradedWindow(
-                    index=idx, start=start, stop=stop,
-                    reason=f"analysis error: {type(e).__name__}",
-                    detail={"error": str(e)})
-            else:
-                wv = WindowVerdict(index=idx, start=start, stop=stop,
-                                   verdict=res.verdict)
+            res = analyzer.analyze_trace(trace, window=window)
+            wv = WindowVerdict(index=idx, start=start, stop=stop,
+                               verdict=res.verdict)
         self.log.append(wv)
         return wv
 

@@ -118,18 +118,16 @@ class TestDeviceLockstep:
                                           (64, 8, 2), (200, 5, 3),
                                           (33, 2, 4), (129, 16, 5)])
     def test_cluster_batch_partitions_match_numpy(self, name, m, n, seed):
-        """Toggle widths 0..n-1 — the shape of Algorithm 2's per-region
-        and composite trials.  A toggle zeroing EVERY column is excluded
-        by design: it leaves a matrix of exact zeros whose partition is
-        pure roundoff residue on host f64 and device f32 alike (and its
-        only consumer, same_partition-vs-baseline, is insensitive to
-        which residue scatter it gets)."""
+        """Toggle widths 0..n — the shape of Algorithm 2's per-region
+        and composite trials, up to a toggle zeroing EVERY column (a
+        matrix of exact zeros: one cluster, whatever residue the deltas
+        leave)."""
         rng = np.random.default_rng(seed)
         W = 50.0 + rng.random((m, n))
         W[: max(1, m // 4)] *= 6.0
         dev = IncrementalClusterState(W, backend=name)
         ref = IncrementalClusterState(W)
-        toggles = [([], 0.0)] + \
+        toggles = [([], 0.0), (list(range(n)), 0.0)] + \
             [([int(c) for c in rng.choice(n, size=rng.integers(1, n),
                                           replace=False)], 0.0)
              for _ in range(7)]
@@ -201,6 +199,106 @@ class TestDeviceLockstep:
         # every call must have amortized >= 1 seed; if per-seed calls
         # leaked back in, calls would equal rows instead
         assert stats["calls"] <= len(stats["per_seed"])
+
+
+@pytest.mark.parametrize("name", ["numpy", "jax", "pallas"])
+def test_all_zero_trial_is_one_exact_cluster(name):
+    """Zeroing every nonzero column leaves a zero matrix — every point at
+    distance 0 from every other, so one cluster, on every lane, batched,
+    sequential or nested.  (Base rows minus toggle deltas would leave
+    roundoff residue there, compared with a zero threshold.)  This is the
+    shape of a serving trace's decode-only window, whose prefill column
+    is exactly zero."""
+    from repro.core import RegionTree, find_dissimilarity_bottlenecks
+    rng = np.random.default_rng(3)
+    W = np.zeros((6, 4))
+    W[:, 1] = 1e-3 * (1.0 + rng.random(6))
+    W[:, 3] = 5e-4 * (1.0 + rng.random(6))
+    W[:3, 1] *= 3.0
+    st = IncrementalClusterState(W, backend=name)
+    assert st.cluster().n_clusters > 1
+    (zero,) = st.cluster_batch([([1, 3], 0.0)])
+    assert zero.n_clusters == 1
+    st.push([1, 3], 0.0)
+    assert st.cluster().n_clusters == 1
+    st.pop()
+    st.push([1], 0.0)
+    (nested,) = st.cluster_batch([([3], 0.0)])
+    assert nested.n_clusters == 1
+    st.pop()
+
+    tree = RegionTree("serve")
+    for j in range(4):
+        tree.add(f"r{j}")
+    rids = list(range(1, 5))
+    got = find_dissimilarity_bottlenecks(tree, W, rids, backend=name)
+    want = find_dissimilarity_bottlenecks(tree, W, rids)
+    assert (got.ccrs, got.cccrs, got.composite_s) == \
+        (want.ccrs, want.cccrs, want.composite_s)
+
+
+def _decode_only_lanes(rng):
+    """Eight serving lanes over four regions: lanes 0-4 ran only the two
+    decode-side regions (1 and 3), lanes 5-7 ran all four, at widely
+    separated rates."""
+    W = np.zeros((8, 4))
+    W[:, 1] = 1e-3 * (1.0 + rng.random(8))
+    W[:, 3] = 5e-4 * (1.0 + rng.random(8))
+    W[5:, 0] = [0.2, 0.9, 4.0]
+    W[5:, 2] = [0.1, 0.5, 2.0]
+    return W
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+@pytest.mark.parametrize("name", ["numpy", "jax", "pallas"])
+def test_rows_zeroed_by_a_toggle_cluster_exactly(name, seed):
+    """Zeroing the decode-side columns leaves lanes 0-4 exactly zero and
+    lanes 5-7 not.  The zero rows are one cluster, exactly as clustering
+    the zeroed matrix from scratch says, on every lane — batched,
+    sequential and nested — though the deltas from the base rows leave
+    roundoff residue, compared with a zero threshold, where those rows
+    should cancel."""
+    from repro.core import optics_cluster
+    W = _decode_only_lanes(np.random.default_rng(seed))
+    Z = W.copy()
+    Z[:, [1, 3]] = 0.0
+    want = optics_cluster(Z)
+    assert want.n_clusters == 4
+    assert len(set(want.labels[:5].tolist())) == 1
+
+    st = IncrementalClusterState(W, backend=name)
+    got = st.cluster_batch([([1, 3], 0.0), ([3], 0.0)])
+    assert got[0].n_clusters == want.n_clusters
+    assert got[0].same_partition(want)
+    st.push([1, 3], 0.0)
+    seq = st.cluster()
+    st.pop()
+    st.push([1], 0.0)
+    (nested,) = st.cluster_batch([([3], 0.0)])
+    st.pop()
+    for res in (seq, nested):
+        assert res.n_clusters == want.n_clusters
+        assert res.same_partition(want)
+
+
+@pytest.mark.parametrize("name", ["jax", "pallas"])
+def test_device_rows_hold_float64_to_threshold_scale(name):
+    """The device lanes' D² rows against float64 at the corpus's
+    magnitudes (W ~ 100, n = 128), where the Gram identity cancels
+    hardest.  The error must stay far below the clustering's threshold²
+    (0.01·|a|² at the default 10%).  With its inputs rounded to bf16 on
+    the way to a TPU's matrix unit the same Gram misses by about 1e-3·|a|²,
+    a tenth of the threshold² and ten times this bound."""
+    rng = np.random.default_rng(0)
+    W = 100.0 + rng.random((512, 128))
+    W[:128] *= 1.05
+    sq = np.einsum("ij,ij->i", W, W)
+    be = get_distance_backend(name)
+    idx = list(range(0, 512, 37))
+    got = be.seed_rows(be.prepare(W, sq), idx)
+    want = _brute_rows(W, idx)
+    err = float(np.abs(got - want).max() / sq[idx].max())
+    assert err < 1e-4, f"max |D² error| = {err!r} · |a|²"
 
 
 class TestHostBatchedFetchMemo:

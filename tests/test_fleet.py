@@ -643,6 +643,20 @@ class TestRunCorpusJobs:
         # identical apart from wall seconds
         norm = lambda s: re.sub(r"\d+\.\d{3}", "W", s)
         assert norm(seq.stdout) == norm(par.stdout)
+        # the pool's workers are pinned to the CPU, and the run says so
+        assert "pool: 2 workers, JAX_PLATFORMS=cpu" in par.stderr
+
+    @pytest.mark.parametrize("backend", ["jax", "pallas"])
+    def test_jobs_refused_with_device_lane(self, backend):
+        """A pool of workers would each claim the accelerator: --jobs > 1
+        with a device distance backend is a usage error, before any
+        entry runs."""
+        p = run_cli("scripts/run_corpus.py", "--jobs", "2",
+                    "--distance-backend", backend,
+                    "--entry", self.ENTRIES[0])
+        assert p.returncode == 2
+        assert "use --jobs 1" in p.stderr
+        assert p.stdout == ""
 
     def test_jobs_fleet_backend(self):
         p = run_cli("scripts/run_corpus.py", "--backend", "fleet",

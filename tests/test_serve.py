@@ -337,3 +337,31 @@ class TestJitBackendSmoke:
             assert float(wall[:, :, :, tr.col(rid)].sum()) > 0.0, path
         tp = engine.throughput()
         assert tp["prefill_tok_per_s"] > 0 and tp["decode_tok_per_s"] > 0
+        assert backend.nonfinite_samples == 0
+
+    def test_nonfinite_logits_are_counted(self):
+        """NaN weights still sample in-vocabulary tokens (argmax of a NaN
+        row is an index); the backend counts every such sampled row."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.configs import get_arch
+        from repro.models import build
+        from repro.serve.runtime import JitBackend
+
+        cfg = get_arch("st-100m").smoke
+        api = build(cfg)
+        params, _ = api.init(jax.random.key(0))
+        params = jax.tree.map(lambda x: jnp.full_like(x, jnp.nan)
+                              if jnp.issubdtype(x.dtype, jnp.floating)
+                              else x, params)
+        traffic = generate_traffic(TrafficConfig(
+            n_requests=2, arrival_rate=10.0, length_buckets=(8,),
+            length_mix=(1.0,), gen_len=2, vocab=cfg.vocab), seed=0)
+        backend = JitBackend(cfg, api, params, lanes=2, max_len=11,
+                             prefill_chunk=8, seed=0)
+        ServeEngine(ServeConfig(lanes=2, max_len=11, prefill_chunk=8),
+                    traffic, backend).run()
+        assert all(0 <= t < cfg.vocab
+                   for v in backend.outputs.values() for t in v)
+        assert backend.nonfinite_samples == 4

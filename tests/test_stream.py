@@ -279,8 +279,50 @@ class TestOnlineAnalyzer:
         override.process_trace(trace)
         assert override.onset("dissimilarity") == 2
 
+    def test_analyzer_exception_propagates(self, monkeypatch):
+        """An analyzer that fails — a device backend that cannot import
+        or compile, a bug — is a fault of the watcher, not of the window:
+        it raises instead of logging a degraded window."""
+        _, tree, trace = drift_trace()
+
+        def boom(self, trace, window=None):
+            raise RuntimeError("backend down")
+
+        monkeypatch.setattr(AutoAnalyzer, "analyze_trace", boom)
+        online = OnlineAnalyzer(tree=tree, window_steps=4)
+        with pytest.raises(RuntimeError, match="backend down"):
+            online.process_trace(trace)
+        assert online.log.windows == []
+
+    def test_non_finite_samples_still_degrade(self):
+        """Bad data stays a degraded window: the stream goes on."""
+        _, tree, trace = drift_trace()
+        metric = sorted(trace.data)[0]
+        trace.data[metric][5] = np.nan
+        online = OnlineAnalyzer(tree=tree, window_steps=4)
+        log = online.process_trace(trace)
+        assert [w.degraded for w in log.windows] == [False, True, False,
+                                                     False]
+        assert log.windows[1].reason == "non-finite samples"
+
 
 class TestWatchTrainCLI:
+    def test_analyzer_exception_exits_nonzero(self, tmp_path):
+        """A window the analyzer fails on ends the watcher with a nonzero
+        exit, not a DEGRADED line and exit 0."""
+        _, _, trace = drift_trace()
+        d = str(tmp_path / "sp")
+        spool_up(trace, d, chunk_steps=4)
+        out = subprocess.run(
+            [sys.executable, os.path.join(REPO, "scripts/watch_train.py"),
+             d, "--analyzer-kw", '{"threshold_frac": "x"}'],
+            capture_output=True, text=True,
+            env={**os.environ,
+                 "PYTHONPATH": os.path.join(REPO, "src")})
+        assert out.returncode not in (0, 3, 4)
+        assert "TypeError" in out.stderr
+        assert "DEGRADED" not in out.stdout
+
     def test_json_stream_and_finalize(self, tmp_path):
         _, _, trace = drift_trace()
         d = str(tmp_path / "sp")
