@@ -24,6 +24,7 @@ from .roughset import DecisionTable
 from .search import (DisparityReport, DissimilarityReport,
                      find_disparity_bottlenecks,
                      find_dissimilarity_bottlenecks)
+from .spans import span
 
 # Human-readable root-cause names for the five attributes (paper a1..a5,
 # TPU-adapted; DESIGN.md §2).
@@ -144,26 +145,30 @@ class AutoAnalyzer:
     def analyze(self, rm: RegionMetrics) -> AnalysisResult:
         rids = [r for r in rm.region_ids
                 if not self._is_management(r)]
-        dis = self._dissimilarity_pass(rm, rids)
-        disp = self._disparity_pass(rm, rids)
-        dis_table = dis_causes = None
-        if dis.exists:
-            dis_table = self._dissimilarity_table(rm, rids)
-            dis_causes = dis_table.reducts()
-        disp_table = self._disparity_table(rm, rids, disp)
-        # Root causes: per-bottleneck discernibility functions (the paper
-        # 'searches the decision table' per region) — the union of each
-        # bottleneck's minimal hitting attributes with a positive value.
-        per_region_attrs: Dict[int, List[str]] = {}
-        union: set = set()
-        for rid in disp.ccrs:
-            idx = disp_table.object_ids.index(rid)
-            reds = disp_table.object_reducts(idx)
-            row = disp_table.rows[idx]
-            pos = {a for red in reds for a in red
-                   if row[disp_table.attributes.index(a)]}
-            union |= pos
-            per_region_attrs[rid] = sorted(pos)
+        with span("analyzer.dissimilarity"):
+            dis = self._dissimilarity_pass(rm, rids)
+        with span("analyzer.disparity"):
+            disp = self._disparity_pass(rm, rids)
+        with span("analyzer.rootcause"):
+            dis_table = dis_causes = None
+            if dis.exists:
+                dis_table = self._dissimilarity_table(rm, rids)
+                dis_causes = dis_table.reducts()
+            disp_table = self._disparity_table(rm, rids, disp)
+            # Root causes: per-bottleneck discernibility functions (the
+            # paper 'searches the decision table' per region) — the union
+            # of each bottleneck's minimal hitting attributes with a
+            # positive value.
+            per_region_attrs: Dict[int, List[str]] = {}
+            union: set = set()
+            for rid in disp.ccrs:
+                idx = disp_table.object_ids.index(rid)
+                reds = disp_table.object_reducts(idx)
+                row = disp_table.rows[idx]
+                pos = {a for red in reds for a in red
+                       if row[disp_table.attributes.index(a)]}
+                union |= pos
+                per_region_attrs[rid] = sorted(pos)
         disp_causes = [frozenset(union)] if union else []
         result = AnalysisResult(
             dissimilarity=dis,
@@ -192,7 +197,9 @@ class AutoAnalyzer:
         to a step window of a long run.  The trace's own deterministic
         reduction feeds :meth:`analyze`, so offline analysis of a saved
         artifact equals the in-process result bit-for-bit."""
-        return self.analyze(trace.reduce(window))
+        with span("analyzer.reduce"):
+            rm = trace.reduce(window)
+        return self.analyze(rm)
 
     def _paths(self, rids: Sequence[int]) -> Tuple[str, ...]:
         out = []

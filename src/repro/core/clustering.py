@@ -34,6 +34,8 @@ from typing import (Callable, Dict, List, Optional, Sequence,
 
 import numpy as np
 
+from .spans import span
+
 # Severity categories (paper §4.2.2).
 VERY_LOW, LOW, MEDIUM, HIGH, VERY_HIGH = 0, 1, 2, 3, 4
 SEVERITY_NAMES = ["very low", "low", "medium", "high", "very high"]
@@ -203,7 +205,9 @@ class _JaxDistanceBackend:
         return self._rows(Wd, sqd, pad)[:k]
 
     def seed_rows(self, handle, idx: Sequence[int]) -> np.ndarray:
-        out = np.asarray(self.device_rows(handle, idx), dtype=np.float64)
+        rows = self.device_rows(handle, idx)
+        with span("clustering.device_wait", site="seed_rows"):
+            out = np.asarray(rows, dtype=np.float64)
         return np.maximum(out, 0.0)
 
 
@@ -241,7 +245,9 @@ class _PallasDistanceBackend:
                                           interpret=self._interpret)[:k]
 
     def seed_rows(self, handle, idx: Sequence[int]) -> np.ndarray:
-        out = np.asarray(self.device_rows(handle, idx), dtype=np.float64)
+        rows = self.device_rows(handle, idx)
+        with span("clustering.device_wait", site="seed_rows"):
+            out = np.asarray(rows, dtype=np.float64)
         return np.maximum(out, 0.0)
 
 
@@ -861,7 +867,8 @@ def _kmeans_lloyd_jax(x: np.ndarray, centroids: np.ndarray,
     with jax.enable_x64(True):
         cent, lab = fn(jnp.asarray(x), jnp.asarray(centroids),
                        n_iter=int(n_iter))
-        return np.asarray(cent), np.asarray(lab)
+        with span("clustering.device_wait", site="kmeans"):
+            return np.asarray(cent), np.asarray(lab)
 
 
 def kmeans_1d(values: np.ndarray, k: int, n_iter: int = 100,

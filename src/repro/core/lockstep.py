@@ -47,6 +47,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .spans import span
+
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def _prep(Wd, Ad, nnz, cols, *, n):
@@ -212,21 +214,25 @@ class DeviceLockstep:
         act_h = np.ones(ntp, dtype=bool)
         p, active = jnp.asarray(p_h), jnp.asarray(act_h)
         while True:
-            self._ensure_rows(
-                sorted({int(q) for q, a in zip(p_h, act_h) if a}))
-            sidx = np.zeros(ntp, dtype=np.int32)
-            for t in np.nonzero(act_h)[0]:
-                sidx[t] = self._slot[int(p_h[t])]
-            labels, ncl, used_thr, p, active = _round(
-                Wc, af, zero, self._sqd, self._rcache, jnp.asarray(sidx), p,
-                active, labels, ncl, used_thr,
-                frac=self._frac, fixed=self._fixed, ct=self._ct)
-            p_h = np.asarray(p)
-            act_h = np.asarray(active)
+            seeds = sorted({int(q) for q, a in zip(p_h, act_h) if a})
+            with span("lockstep.round", trials=int(act_h.sum()),
+                      seeds=len(seeds)):
+                self._ensure_rows(seeds)
+                sidx = np.zeros(ntp, dtype=np.int32)
+                for t in np.nonzero(act_h)[0]:
+                    sidx[t] = self._slot[int(p_h[t])]
+                labels, ncl, used_thr, p, active = _round(
+                    Wc, af, zero, self._sqd, self._rcache, jnp.asarray(sidx),
+                    p, active, labels, ncl, used_thr,
+                    frac=self._frac, fixed=self._fixed, ct=self._ct)
+                with span("clustering.device_wait", site="lockstep"):
+                    p_h = np.asarray(p)
+                    act_h = np.asarray(active)
             if not act_h.any():
                 break
         # Labels stay int32 — every consumer (same_partition, bincount,
         # members) is dtype-agnostic, and the int64 upcast would double
         # the pull cost at fleet shapes.
-        lab = np.asarray(labels)[:nt]
-        return lab, np.asarray(ncl[:nt]), np.asarray(used_thr[:nt])
+        with span("clustering.device_wait", site="lockstep"):
+            return (np.asarray(labels)[:nt], np.asarray(ncl[:nt]),
+                    np.asarray(used_thr[:nt]))

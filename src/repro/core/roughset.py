@@ -14,9 +14,12 @@ Worked examples from the paper are unit-tested:
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
+
+from .spans import span
 
 # The reduct search enumerates attribute subsets by size — O(2^|A|) in the
 # worst case.  The paper's decision tables have 5 attributes; anything past
@@ -45,15 +48,16 @@ def _minimal_hitting_sets(
             "reducer")
     forced = frozenset(a for c in clauses if len(c) == 1 for a in c)
     hits: List[FrozenSet[str]] = []
-    for size in range(max(1, len(forced)), len(attrs) + 1):
-        for combo in itertools.combinations(attrs, size):
-            s = frozenset(combo)
-            if not forced <= s:
-                continue  # misses a singleton clause
-            if all(s & c for c in clauses):
-                hits.append(s)
-        if hits:
-            break  # all minimum-size hitting sets found
+    with span("roughset.reducts", clauses=len(clauses)):
+        for size in range(max(1, len(forced)), len(attrs) + 1):
+            for combo in itertools.combinations(attrs, size):
+                s = frozenset(combo)
+                if not forced <= s:
+                    continue  # misses a singleton clause
+                if all(s & c for c in clauses):
+                    hits.append(s)
+            if hits:
+                break  # all minimum-size hitting sets found
     return hits
 
 
@@ -103,17 +107,23 @@ class DecisionTable:
         """
         n = len(self.rows)
         clauses = set()
-        for i in range(n):
-            for j in range(i + 1, n):
-                if self.decisions[i] != self.decisions[j]:
-                    diff = frozenset(
-                        a for k, a in enumerate(self.attributes)
-                        if self.rows[i][k] != self.rows[j][k])
-                    if diff:
-                        clauses.add(diff)
-        # Absorption: drop any clause that is a superset of another.
-        minimal = [c for c in clauses
-                   if not any(o < c for o in clauses)]
+        with span("roughset.discernibility", objects=n) as sp:
+            for i in range(n):
+                for j in range(i + 1, n):
+                    if self.decisions[i] != self.decisions[j]:
+                        diff = frozenset(
+                            a for k, a in enumerate(self.attributes)
+                            if self.rows[i][k] != self.rows[j][k])
+                        if diff:
+                            clauses.add(diff)
+            # Absorption: drop any clause that is a superset of another.
+            minimal = [c for c in clauses
+                       if not any(o < c for o in clauses)]
+            if sp:
+                same = collections.Counter(self.decisions).values()
+                sp.set(pairs=n * (n - 1) // 2
+                       - sum(c * (c - 1) // 2 for c in same),
+                       clauses=len(minimal))
         return sorted(minimal, key=lambda c: (len(c), sorted(c)))
 
     # -- reducts / core --------------------------------------------------
@@ -129,16 +139,23 @@ class DecisionTable:
     def object_clauses(self, index: int) -> List[FrozenSet[str]]:
         """Clauses of the per-object discernibility function f_i (the paper
         computes 'the discernibility functions of each object')."""
+        n = len(self.rows)
         clauses = set()
-        for j in range(len(self.rows)):
-            if j == index or self.decisions[index] == self.decisions[j]:
-                continue
-            diff = frozenset(
-                a for k, a in enumerate(self.attributes)
-                if self.rows[index][k] != self.rows[j][k])
-            if diff:
-                clauses.add(diff)
-        return [c for c in clauses if not any(o < c for o in clauses)]
+        with span("roughset.discernibility", objects=n) as sp:
+            for j in range(n):
+                if j == index or self.decisions[index] == self.decisions[j]:
+                    continue
+                diff = frozenset(
+                    a for k, a in enumerate(self.attributes)
+                    if self.rows[index][k] != self.rows[j][k])
+                if diff:
+                    clauses.add(diff)
+            minimal = [c for c in clauses
+                       if not any(o < c for o in clauses)]
+            if sp:
+                sp.set(pairs=n - self.decisions.count(self.decisions[index]),
+                       clauses=len(minimal))
+        return minimal
 
     def object_reducts(self, index: int) -> List[FrozenSet[str]]:
         """Minimal hitting sets of the per-object clauses: the attributes

@@ -43,7 +43,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
 
-from repro.core import WALL_TIME, RegionTree
+from repro.core import RegionTree
+from repro.core.spans import span
 from repro.core.trace import RegionTrace
 
 PREFILL = "prefill"
@@ -259,11 +260,6 @@ class ServeEngine:
         self.wall_s = 0.0
         self.tokens_prefill = 0
         self.tokens_decode = 0
-        root = self.tree.root.name
-        self._wall_cols = {
-            phase: self.tree.by_path(f"{root}/{phase}").region_id
-            for phase in (PREFILL, DECODE, SAMPLE)}
-        self._phase_wall = {phase: 0.0 for phase in self._wall_cols}
         self.trace: Optional[RegionTrace] = None
         self._step_traces: List[RegionTrace] = []
         self._last_step_trace: Optional[RegionTrace] = None
@@ -294,23 +290,24 @@ class ServeEngine:
         if self.scfg.max_steps is not None \
                 and self.step_idx >= self.scfg.max_steps:
             return False
-        events = self.sched.step(self.step_idx)
-        step_trace = self.backend.execute(self.step_idx, events)
-        if self.step_hook is not None:
-            self.step_hook(self, self.step_idx, step_trace)
-        wall = step_trace.metric(WALL_TIME)
-        for phase, rid in self._wall_cols.items():
-            self._phase_wall[phase] += float(
-                wall[:, :, :, step_trace.col(rid)].sum())
-        for ev in events:
-            self.tokens_prefill += ev.prefill_tokens
-            self.tokens_decode += ev.decode_tokens
-        if self.spool is not None:
-            self.spool.append(step_trace)
-        else:
-            self._step_traces.append(step_trace)
-        self._last_step_trace = step_trace
-        self.step_idx += 1
+        with span("serve.step") as sp:
+            with span("serve.schedule"):
+                events = self.sched.step(self.step_idx)
+            with span("serve.execute"):
+                step_trace = self.backend.execute(self.step_idx, events)
+            if self.step_hook is not None:
+                self.step_hook(self, self.step_idx, step_trace)
+            prefill = sum(ev.prefill_tokens for ev in events)
+            decode = sum(ev.decode_tokens for ev in events)
+            self.tokens_prefill += prefill
+            self.tokens_decode += decode
+            sp.set(prefill_tokens=prefill, decode_tokens=decode)
+            if self.spool is not None:
+                self.spool.append(step_trace)
+            else:
+                self._step_traces.append(step_trace)
+            self._last_step_trace = step_trace
+            self.step_idx += 1
         return True
 
     def run(self, finalize: bool = True) -> Optional[RegionTrace]:
@@ -358,17 +355,18 @@ class ServeEngine:
         return self.trace
 
     def throughput(self) -> Dict[str, float]:
-        """Warmup-excluded serving throughput, prefill and decode split
-        out (each phase's tokens over that phase's own region wall)."""
-        pre_w = self._phase_wall[PREFILL]
-        dec_w = self._phase_wall[DECODE] + self._phase_wall[SAMPLE]
+        """Warmup-excluded serving throughput of :meth:`run`: prefill,
+        decode and all tokens, each over the run's wall (prefill chunks,
+        decode calls and the engine's host work all share that wall, so a
+        phase's own region walls would overstate its rate)."""
+        w = self.wall_s
         total = self.tokens_prefill + self.tokens_decode
         return {
-            "wall_s": self.wall_s,
+            "wall_s": w,
             "requests_completed": float(self.sched.completed),
             "tokens_prefill": float(self.tokens_prefill),
             "tokens_decode": float(self.tokens_decode),
-            "prefill_tok_per_s": self.tokens_prefill / pre_w if pre_w else 0.0,
-            "decode_tok_per_s": self.tokens_decode / dec_w if dec_w else 0.0,
-            "tok_per_s": total / self.wall_s if self.wall_s else 0.0,
+            "prefill_tok_per_s": self.tokens_prefill / w if w else 0.0,
+            "decode_tok_per_s": self.tokens_decode / w if w else 0.0,
+            "tok_per_s": total / w if w else 0.0,
         }

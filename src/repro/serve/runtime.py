@@ -40,6 +40,7 @@ from repro.core import (BYTES, CPU_TIME, FLOPS, RAW_METRICS, VMEM_PRESSURE,
                         WALL_TIME)
 from repro.core.collector import _pick_cpu_clock
 from repro.core.hlo import cost_analysis_of
+from repro.core.spans import span
 from repro.core.trace import RegionTrace
 from repro.models import ModelApi, encdec
 from repro.scenarios.traffic import prompt_tokens
@@ -90,6 +91,8 @@ class JitBackend:
             lambda logits: (jnp.argmax(logits[:, -1:], axis=-1)
                             .astype(jnp.int32),
                             jnp.isfinite(logits[:, -1]).all()))
+        # What the current ``_timed`` call serves, for its spans.
+        self._call: Dict[str, Any] = {}
         # Per-lane decode state.
         self._state: List[Any] = [None] * lanes
         self._pending_logits: List[Any] = [None] * lanes
@@ -151,10 +154,16 @@ class JitBackend:
 
     # -- execution ---------------------------------------------------------
     def _timed(self, fn, *args):
+        """Call ``fn``, wait for its result, and return it with the wall
+        and CPU seconds taken.  Under a profiler session the call is split
+        into ``serve.dispatch`` (until ``fn`` returns) and ``serve.wait``
+        (until the device is done), labelled by :attr:`_call`."""
         t0w = time.perf_counter()
         t0c = self._clock()
-        out = fn(*args)
-        jax.block_until_ready(out)
+        with span("serve.dispatch", **self._call):
+            out = fn(*args)
+        with span("serve.wait", **self._call):
+            jax.block_until_ready(out)
         return out, time.perf_counter() - t0w, self._clock() - t0c
 
     def execute(self, s: int, events: Sequence[LaneEvent]) -> RegionTrace:
@@ -179,6 +188,8 @@ class JitBackend:
                 pos = jnp.arange(a, a + k, dtype=jnp.int32) if k > 1 \
                     else jnp.int32(a)
                 fl, by = self._costs_for(toks, pos, self._state[lane])
+                self._call = {"kind": PREFILL, "lane": lane,
+                              "rid": req.rid, "pos": a}
                 (logits, new_state), dw, dc = self._timed(
                     self._decode, self.params, self._state[lane], toks, pos)
                 self._state[lane] = new_state
@@ -188,6 +199,8 @@ class JitBackend:
             if ev.decode_tokens:
                 # Sample the pending logits (its own timed region), then
                 # feed the sampled token to produce the next logits.
+                self._call = {"kind": SAMPLE, "lane": lane, "rid": req.rid,
+                              "pos": ev.decode_pos}
                 (tok, finite), dw, dc = self._timed(
                     self._sample, self._pending_logits[lane])
                 sfl, sby = self._sample_cost or (0.0, 0.0)
@@ -196,6 +209,7 @@ class JitBackend:
                 self.nonfinite_samples += not bool(finite)
                 pos = jnp.int32(ev.decode_pos)
                 fl, by = self._costs_for(tok, pos, self._state[lane])
+                self._call = dict(self._call, kind=DECODE)
                 (logits, new_state), dw, dc = self._timed(
                     self._decode, self.params, self._state[lane], tok, pos)
                 self._state[lane] = new_state

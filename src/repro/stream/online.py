@@ -32,6 +32,7 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 import numpy as np
 
 from repro.core import AutoAnalyzer, Verdict, tree_from_schema
+from repro.core.spans import span
 from repro.core.trace import RegionTrace, TraceFormatError
 
 from .spool import SpooledTrace, SpoolGapError, StallDetector
@@ -270,26 +271,27 @@ class OnlineAnalyzer:
         :meth:`pending_bounds`), degrading instead of crashing: a range
         lost to quarantine/compaction or a segment that fails to parse
         logs a :class:`DegradedWindow` and the stream continues."""
-        analyzer = self._resolve_analyzer(spooled.schema, spooled.meta)
-        self._handed = max(0, self._handed - 1)
-        try:
-            win = spooled.window(start, stop)
-        except SpoolGapError as e:
-            wv: AnyWindow = DegradedWindow(
-                index=len(self.log.windows), start=start, stop=stop,
-                reason="window range lost",
-                detail={"missing": [list(m) for m in e.missing]})
-            self.log.append(wv)
-            return wv
-        except TraceFormatError as e:
-            wv = DegradedWindow(
-                index=len(self.log.windows), start=start, stop=stop,
-                reason="corrupt segment",
-                detail={"path": e.path, "error": e.reason})
-            self.log.append(wv)
-            return wv
-        return self._analyze_window(win, (0, win.n_steps), start, stop,
-                                    analyzer)
+        with span("online.consume", start=start, stop=stop):
+            analyzer = self._resolve_analyzer(spooled.schema, spooled.meta)
+            self._handed = max(0, self._handed - 1)
+            try:
+                win = spooled.window(start, stop)
+            except SpoolGapError as e:
+                wv: AnyWindow = DegradedWindow(
+                    index=len(self.log.windows), start=start, stop=stop,
+                    reason="window range lost",
+                    detail={"missing": [list(m) for m in e.missing]})
+                self.log.append(wv)
+                return wv
+            except TraceFormatError as e:
+                wv = DegradedWindow(
+                    index=len(self.log.windows), start=start, stop=stop,
+                    reason="corrupt segment",
+                    detail={"path": e.path, "error": e.reason})
+                self.log.append(wv)
+                return wv
+            return self._analyze_window(win, (0, win.n_steps), start, stop,
+                                        analyzer)
 
     def skip(self, start: int, stop: int, reason: str,
              detail: Optional[Dict[str, Any]] = None) -> DegradedWindow:

@@ -59,6 +59,7 @@ import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 from repro.core.faultpoints import fault_point
+from repro.core.spans import span
 from repro.core.trace import RegionTrace, TraceFormatError
 
 SPOOL_FORMAT_VERSION = 2
@@ -210,33 +211,36 @@ class TraceSpool:
         else:
             # fail at the offending append, not at a later flush/merge
             RegionTrace.check_mergeable(self._head, step_trace)
-        self._pending.append(step_trace)
-        self._pending_steps += step_trace.n_steps
-        if self._pending_steps >= self.chunk_steps:
-            self._flush()
+        with span("spool.append"):
+            self._pending.append(step_trace)
+            self._pending_steps += step_trace.n_steps
+            if self._pending_steps >= self.chunk_steps:
+                self._flush()
 
     def _flush(self) -> None:
         if not self._pending:
             return
-        seg = (self._pending[0] if len(self._pending) == 1
-               else RegionTrace.merge(self._pending))
-        fname = f"segment-{self._seg_counter:05d}.npz"
-        self._seg_counter += 1
-        final = os.path.join(self.directory, fname)
-        tmp = final + ".tmp"
-        fault_point("spool.segment.pre_write")
-        seg.save(tmp)
-        fault_point("spool.segment.written")
-        digest, nbytes = _file_digest(tmp)
-        os.replace(tmp, final)
-        fault_point("spool.segment.renamed")
-        self._segments.append(
-            {"file": fname, "start": self._n_steps, "n_steps": seg.n_steps,
-             "bytes": nbytes, "sha256": digest})
-        self._n_steps += seg.n_steps
-        self._pending = []
-        self._pending_steps = 0
-        self._write_manifest(complete=False, meta=self._meta)
+        with span("spool.flush") as sp:
+            seg = (self._pending[0] if len(self._pending) == 1
+                   else RegionTrace.merge(self._pending))
+            fname = f"segment-{self._seg_counter:05d}.npz"
+            self._seg_counter += 1
+            final = os.path.join(self.directory, fname)
+            tmp = final + ".tmp"
+            fault_point("spool.segment.pre_write")
+            seg.save(tmp)
+            fault_point("spool.segment.written")
+            digest, nbytes = _file_digest(tmp)
+            os.replace(tmp, final)
+            fault_point("spool.segment.renamed")
+            self._segments.append(
+                {"file": fname, "start": self._n_steps,
+                 "n_steps": seg.n_steps, "bytes": nbytes, "sha256": digest})
+            self._n_steps += seg.n_steps
+            self._pending = []
+            self._pending_steps = 0
+            self._write_manifest(complete=False, meta=self._meta)
+            sp.set(bytes=nbytes)
 
     def _write_manifest(self, complete: bool,
                         meta: Optional[Dict[str, Any]]) -> None:
@@ -621,11 +625,18 @@ class SpooledTrace:
         missing = self.missing_ranges(start, stop)
         if missing:
             raise SpoolGapError(self.directory, start, stop, missing)
-        idxs = self._covering(start, stop)
-        traces = [self.segment(i) for i in idxs]
-        merged = traces[0] if len(traces) == 1 else RegionTrace.merge(traces)
-        base = self._doc["segments"][idxs[0]]["start"]
-        return merged.window(start - base, stop - base)
+        with span("spool.window", start=start, stop=stop):
+            idxs = self._covering(start, stop)
+            traces = []
+            for i in idxs:
+                with span("spool.load",
+                          bytes=self._doc["segments"][i].get("bytes")):
+                    traces.append(self.segment(i))
+            with span("spool.assemble"):
+                merged = (traces[0] if len(traces) == 1
+                          else RegionTrace.merge(traces))
+                base = self._doc["segments"][idxs[0]]["start"]
+                return merged.window(start - base, stop - base)
 
     def to_trace(self) -> RegionTrace:
         """Reassemble the whole retained run, applying the producer's final

@@ -166,6 +166,16 @@ class TestCostModelEngine:
         assert meta["tokens_prefill"] == 128
         assert meta["tokens_decode"] == 48
 
+    def test_throughput_is_tokens_over_the_run_wall(self):
+        """Every rate divides by the same wall, the run's, so prefill
+        and decode rates add up to the total rate."""
+        engine = self._run(saturated_sessions(4, 2), steps=None)
+        tp = engine.throughput()
+        assert tp["wall_s"] == engine.wall_s > 0
+        assert tp["decode_tok_per_s"] == pytest.approx(48 / engine.wall_s)
+        assert tp["prefill_tok_per_s"] + tp["decode_tok_per_s"] \
+            == pytest.approx(tp["tok_per_s"])
+
 
 class TestServingCorpus:
     def test_registry_shape(self):
