@@ -19,12 +19,55 @@ import dataclasses
 import itertools
 from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from .spans import span
 
 # The reduct search enumerates attribute subsets by size — O(2^|A|) in the
 # worst case.  The paper's decision tables have 5 attributes; anything past
 # this bound is a modelling error, not a bigger search.
 MAX_EXHAUSTIVE_ATTRIBUTES = 20
+
+# Class pairs per block of the clause search: bounds its working memory
+# (8 MB a bitmask word) whatever the number of classes.
+_BLOCK = 1 << 20
+# Attributes per bitmask word: a word stays a non-negative int64.
+_WORD = 62
+
+
+def _factorise(rows: Sequence[Tuple], width: int) -> np.ndarray:
+    """Each column's values as integer codes, equal values (hash-and-``==``)
+    sharing a code: a (len(rows), width) int64 array."""
+    cols: List[Dict] = [{} for _ in range(width)]
+    codes = [[col.setdefault(v, len(col)) for col, v in zip(cols, r)]
+             for r in rows]
+    return np.array(codes, dtype=np.int64).reshape(len(rows), width)
+
+
+def _difference_masks(codes: np.ndarray, dec: np.ndarray) -> Tuple[set, int]:
+    """The distinct sets of differing attributes over the pairs i < j of
+    ``codes`` rows whose ``dec`` differ, each as an int whose bit k is
+    attribute k; and the number of pairs compared."""
+    n, width = codes.shape
+    n_words = max(1, -(-width // _WORD))
+    masks: set = set()
+    compared = 0
+    step = max(1, _BLOCK // max(1, n))
+    for lo in range(0, n, step):
+        hi, rest = min(n, lo + step), slice(lo + 1, n)
+        keep = ((np.arange(lo + 1, n) > np.arange(lo, hi)[:, None])
+                & (dec[lo:hi, None] != dec[None, rest]))
+        words = np.zeros((n_words,) + keep.shape, np.int64)
+        for k in range(width):
+            words[k // _WORD] |= ((codes[lo:hi, None, k]
+                                   != codes[None, rest, k]) << k % _WORD)
+        pairs = words[:, keep]
+        compared += pairs.shape[1]
+        uniq = (np.unique(pairs[0])[:, None] if n_words == 1
+                else np.unique(pairs.T, axis=0))
+        masks.update(sum(int(w) << _WORD * i for i, w in enumerate(u))
+                     for u in uniq)
+    return masks, compared
 
 
 def _minimal_hitting_sets(
@@ -98,24 +141,41 @@ class DecisionTable:
         return mat
 
     # -- Eq. 4 ----------------------------------------------------------
+    def _classes(self) -> List[int]:
+        """One object of each indiscernibility class of U/IND(A ∪ {d}),
+        in first-seen order.
+
+        Two objects with equal rows and equal decisions add the same
+        clause, or none, against any third object, so the clauses over
+        these representatives are the clauses over all objects.  Equality
+        is hash-and-``==``, as the ``!=`` of Eq. 3.
+        """
+        first: Dict[Tuple, int] = {}
+        for i, key in enumerate(zip(self.rows, self.decisions)):
+            first.setdefault(key, i)
+        return list(first.values())
+
     def discernibility_clauses(self) -> List[FrozenSet[str]]:
         """The non-empty, absorption-minimal clauses of f_Λ (CNF).
 
         Empty entries for *differing* decisions (inconsistent objects, which
         do occur — e.g. paper Table 4 rows 5 vs 11) are skipped, the standard
         treatment for inconsistent decision systems.
+
+        Only class representatives are compared, as integer codes in
+        blocks of pairs: a table of a few distinct rows tiled over
+        thousands of ranks costs what the few rows cost.
         """
         n = len(self.rows)
-        clauses = set()
         with span("roughset.discernibility", objects=n) as sp:
-            for i in range(n):
-                for j in range(i + 1, n):
-                    if self.decisions[i] != self.decisions[j]:
-                        diff = frozenset(
-                            a for k, a in enumerate(self.attributes)
-                            if self.rows[i][k] != self.rows[j][k])
-                        if diff:
-                            clauses.add(diff)
+            reps = self._classes()
+            codes = _factorise([self.rows[i] for i in reps],
+                               len(self.attributes))
+            dec = _factorise([(self.decisions[i],) for i in reps], 1)[:, 0]
+            masks, compared = _difference_masks(codes, dec)
+            clauses = {frozenset(a for k, a in enumerate(self.attributes)
+                                 if m >> k & 1)
+                       for m in masks if m}
             # Absorption: drop any clause that is a superset of another.
             minimal = [c for c in clauses
                        if not any(o < c for o in clauses)]
@@ -123,7 +183,8 @@ class DecisionTable:
                 same = collections.Counter(self.decisions).values()
                 sp.set(pairs=n * (n - 1) // 2
                        - sum(c * (c - 1) // 2 for c in same),
-                       clauses=len(minimal))
+                       clauses=len(minimal), classes=len(reps),
+                       class_pairs=compared)
         return sorted(minimal, key=lambda c: (len(c), sorted(c)))
 
     # -- reducts / core --------------------------------------------------
@@ -138,23 +199,26 @@ class DecisionTable:
 
     def object_clauses(self, index: int) -> List[FrozenSet[str]]:
         """Clauses of the per-object discernibility function f_i (the paper
-        computes 'the discernibility functions of each object')."""
+        computes 'the discernibility functions of each object'): object i
+        against one representative of each class of another decision."""
         n = len(self.rows)
         clauses = set()
         with span("roughset.discernibility", objects=n) as sp:
-            for j in range(n):
-                if j == index or self.decisions[index] == self.decisions[j]:
-                    continue
+            reps = self._classes()
+            row, decision = self.rows[index], self.decisions[index]
+            others = [j for j in reps if self.decisions[j] != decision]
+            for j in others:
                 diff = frozenset(
                     a for k, a in enumerate(self.attributes)
-                    if self.rows[index][k] != self.rows[j][k])
+                    if row[k] != self.rows[j][k])
                 if diff:
                     clauses.add(diff)
             minimal = [c for c in clauses
                        if not any(o < c for o in clauses)]
             if sp:
-                sp.set(pairs=n - self.decisions.count(self.decisions[index]),
-                       clauses=len(minimal))
+                sp.set(pairs=n - self.decisions.count(decision),
+                       clauses=len(minimal), classes=len(reps),
+                       class_pairs=len(others))
         return minimal
 
     def object_reducts(self, index: int) -> List[FrozenSet[str]]:

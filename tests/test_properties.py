@@ -1,4 +1,7 @@
 """Property-based tests (hypothesis) for the system's invariants."""
+import itertools
+from unittest import mock
+
 import numpy as np
 import pytest
 
@@ -6,7 +9,7 @@ pytest.importorskip("hypothesis", reason="hypothesis not installed")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (DecisionTable, RegionMetrics, kmeans_severity,
-                        optics_cluster)
+                        optics_cluster, roughset)
 from repro.optim import dequantize_int8, quantize_int8
 
 nice_floats = st.floats(min_value=0.0, max_value=1e6, allow_nan=False,
@@ -125,6 +128,90 @@ class TestRoughSetProperties:
         for i in range(len(t.rows)):
             for red in t.object_reducts(i):
                 assert red <= frozenset(t.attributes)
+
+
+def _pair_loop_clauses(t, objects):
+    """The absorption-minimal clauses of every pair (i, j), i in
+    ``objects``, whose decisions differ: the brute-force oracle."""
+    clauses = set()
+    for i in objects:
+        for j in range(len(t.rows)):
+            if t.decisions[i] != t.decisions[j]:
+                diff = frozenset(a for k, a in enumerate(t.attributes)
+                                 if t.rows[i][k] != t.rows[j][k])
+                if diff:
+                    clauses.add(diff)
+    return {c for c in clauses if not any(o < c for o in clauses)}
+
+
+def _brute_reducts(clauses):
+    attrs = sorted({a for c in clauses for a in c})
+    for size in range(1, len(attrs) + 1):
+        hits = {frozenset(s) for s in itertools.combinations(attrs, size)
+                if all(set(s) & c for c in clauses)}
+        if hits:
+            return hits
+    return set()
+
+
+# Values that are equal across types (1 == 1.0 == True) as well as apart.
+_VALUES = st.sampled_from([0, 1, 2, 1.0, True, False, "lo", "hi"])
+
+
+@st.composite
+def tiled_tables(draw):
+    """2-6 distinct rows tiled to 50-300 objects; a few objects take
+    another decision than their row's, so inconsistent pairs occur."""
+    n_attr = draw(st.integers(1, 6))
+    distinct = draw(st.lists(st.tuples(*[_VALUES] * n_attr),
+                             min_size=2, max_size=6))
+    row_dec = [draw(st.sampled_from([0, 1, 2, "N"])) for _ in distinct]
+    rnd = draw(st.randoms(use_true_random=False))
+    rows, decisions = [], []
+    for _ in range(draw(st.integers(50, 300))):
+        k = rnd.randrange(len(distinct))
+        rows.append(distinct[k])
+        decisions.append(row_dec[k] if rnd.random() < 0.9
+                         else rnd.choice([0, 1, 2, "N"]))
+    return DecisionTable(attributes=[f"a{i}" for i in range(n_attr)],
+                         rows=rows, decisions=decisions)
+
+
+@st.composite
+def distinct_tables(draw):
+    n_attr = draw(st.integers(1, 5))
+    rows = draw(st.lists(st.tuples(*[st.integers(0, 9)] * n_attr),
+                         min_size=2, max_size=60, unique=True))
+    decisions = [draw(st.integers(0, 3)) for _ in rows]
+    return DecisionTable(attributes=[f"a{i}" for i in range(n_attr)],
+                         rows=rows, decisions=decisions)
+
+
+class TestClassReductionMatchesPairLoop:
+    """The clause search over indiscernibility classes gives the clauses
+    of the loop over every pair of objects, whatever the block of class
+    pairs and the width of a bitmask word."""
+
+    @pytest.mark.parametrize("tables", [tiled_tables(), distinct_tables(),
+                                        decision_tables()],
+                             ids=["tiled", "distinct", "small"])
+    @given(data=st.data(), block=st.sampled_from([1, 3, 64, 1 << 20]),
+           word=st.sampled_from([1, 2, 62]))
+    @settings(max_examples=40, deadline=None)
+    def test_clauses_reducts_and_object_clauses(self, tables, data, block,
+                                                word):
+        t = data.draw(tables)
+        with mock.patch.object(roughset, "_BLOCK", block), \
+                mock.patch.object(roughset, "_WORD", word):
+            clauses = t.discernibility_clauses()
+            reducts = t.reducts()
+            per_object = [t.object_clauses(i) for i in range(len(t.rows))]
+        want = _pair_loop_clauses(t, range(len(t.rows)))
+        assert clauses == sorted(want, key=lambda c: (len(c), sorted(c)))
+        assert set(reducts) == _brute_reducts(want)
+        for i, got in enumerate(per_object):
+            assert len(got) == len(set(got))
+            assert set(got) == _pair_loop_clauses(t, [i])
 
 
 class TestCRNMProperties:

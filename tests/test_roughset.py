@@ -40,6 +40,33 @@ class TestPaperTables:
         assert t.explain(13, red) == ["a2"]
 
 
+class TestIndiscernibilityClasses:
+    def test_table3_tiled_to_2048_ranks(self, tmp_path):
+        """Table 3's 8 processes tiled 256x: the reduct is still {a5}, and
+        the clause search compares its 7 classes, not the 2048 ranks."""
+        import jax
+
+        from repro.core import spans
+        base = paper_table3()
+        t = DecisionTable(attributes=base.attributes, rows=base.rows * 256,
+                          decisions=base.decisions * 256)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        spans.take()
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            reds = t.reducts()
+        got = [s.attrs for s in spans.take()["spans"]
+               if s.name == "roughset.discernibility"]
+        assert reds == [frozenset({"a5"})]
+        # decisions 0..4 hold 256, 512, 256, 512, 512 ranks:
+        # C(2048, 2) - 2 C(256, 2) - 3 C(512, 2) pairs differ
+        assert got == [{"objects": 2048, "pairs": 1638400, "clauses": 1,
+                        "classes": 7, "class_pairs": 19}]
+        for i in range(len(base.rows)):
+            assert t.object_reducts(i) == base.object_reducts(i)
+            assert t.object_reducts(i + 8 * 255) == base.object_reducts(i)
+
+
 class TestMechanics:
     def test_matrix_symmetric_entries(self):
         t = paper_table2()

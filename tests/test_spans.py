@@ -146,6 +146,10 @@ def test_analyzer_spans_nest_by_window(recorder, tmp_path):
             assert s.attrs["objects"] > 0 and s.attrs["clauses"] >= 0
             assert 0 <= s.attrs["pairs"] <= \
                 s.attrs["objects"] * (s.attrs["objects"] - 1) // 2
+            assert 1 <= s.attrs["classes"] <= s.attrs["objects"]
+            assert 0 <= s.attrs["class_pairs"] <= min(
+                s.attrs["pairs"],
+                s.attrs["classes"] * (s.attrs["classes"] - 1) // 2)
         if s.name == "lockstep.round":
             assert s.attrs["trials"] >= 1 and s.attrs["seeds"] >= 1
             assert any(c.parent == s.id and c.attrs["site"] == "lockstep"
