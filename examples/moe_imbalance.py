@@ -36,7 +36,7 @@ def expert_load_clusters(history, n_experts):
 def run(aux_weight: float, steps: int = 40):
     base = get_arch("mixtral-8x22b").smoke
     cfg = base.with_(moe=MoEConfig(
-        n_experts=4, top_k=2, n_shared=0, d_ff=64, capacity_factor=2.0,
+        n_experts=4, top_k=2, n_shared=0, d_ff=64,
         sharding="tp", aux_loss_weight=aux_weight))
     trainer = Trainer(
         cfg, AdamWConfig(lr=1e-2, warmup_steps=2, total_steps=steps),

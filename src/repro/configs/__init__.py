@@ -1,7 +1,7 @@
 from .base import (SHAPES, ArchEntry, InputShape, MLAConfig, ModelConfig,
-                   MoEConfig, RecurrentConfig, get_arch, list_archs,
-                   register, shapes_for)
+                   MoEConfig, RecurrentConfig, YarnConfig, get_arch,
+                   list_archs, register, shapes_for)
 
 __all__ = ["SHAPES", "ArchEntry", "InputShape", "MLAConfig", "ModelConfig",
-           "MoEConfig", "RecurrentConfig", "get_arch", "list_archs",
-           "register", "shapes_for"]
+           "MoEConfig", "RecurrentConfig", "YarnConfig", "get_arch",
+           "list_archs", "register", "shapes_for"]

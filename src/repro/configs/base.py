@@ -14,15 +14,27 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    n_experts: int = 8             # routed experts the router scores
     top_k: int = 2
     n_shared: int = 0
     d_ff: int = 0                  # per-expert hidden
-    capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
     # 'ep' shards the expert dim over the model axis; 'tp' shards each
     # expert's hidden dim (used when n_experts < model-axis size).
     sharding: str = "ep"
+    # Expert parallelism: the layer holds experts first_held ..
+    # first_held + held - 1 of the n_experts (held 0: all of them) and
+    # computes their part of the result; the router still scores all.
+    held: int = 0
+    first_held: int = 0
+    norm_topk_prob: bool = True    # renormalise the top-k gates to sum 1
+    # Leading dense layers (first_k_dense_replace), SwiGLU of the model's
+    # d_ff, before the expert layers.
+    first_dense: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +46,21 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 applies it:
+    the rotary frequencies blend the interpolated (``/ factor``) and the
+    original ones over the dims between the beta_fast and beta_slow
+    correction dims, and the softmax scale gains ``mscale ** 2``."""
+
+    factor: float = 1.0
+    original_max_positions: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,6 +88,7 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rope_style: str = "half"       # half | interleaved | partial (chatglm 2d)
     rope_fraction: float = 1.0     # fraction of head_dim rotated
+    yarn: Optional["YarnConfig"] = None
     window: Optional[int] = None   # sliding-window size (SWA)
     causal: bool = True
     attn_logit_softcap: Optional[float] = None
@@ -122,13 +150,13 @@ class ModelConfig:
                 kv_a = d * (m.kv_lora_rank + m.rope_head_dim)
                 kv_b = m.kv_lora_rank * H * (m.nope_head_dim + m.v_head_dim)
                 o = H * m.v_head_dim * d
-                return q + kv_a + kv_b + o
+                return q + kv_a + kv_b + o + m.kv_lora_rank
             return d * H * dh + 2 * d * KV * dh + H * dh * d
 
         def mlp_params(ff: int) -> int:
             return 3 * d * ff  # gate, up, down
 
-        def layer_params() -> int:
+        def layer_params(moe: bool = True) -> int:
             p = 2 * d  # norms
             if self.family in ("ssm",):
                 r = self.recurrent
@@ -137,10 +165,10 @@ class ModelConfig:
                 cm = 2 * d * self.d_ff // 1 if False else d * self.d_ff * 2
                 return p + tm + cm
             p += attn_params() if self.family != "ssm" else 0
-            if self.moe:
+            if self.moe and moe:
                 mo = self.moe
                 p += d * mo.n_experts  # router
-                p += mo.n_experts * mlp_params(mo.d_ff)
+                p += mo.n_held * mlp_params(mo.d_ff)
                 p += mo.n_shared * mlp_params(mo.d_ff)
             else:
                 p += mlp_params(self.d_ff)
@@ -163,15 +191,19 @@ class ModelConfig:
             att_p = attn_params() + 2 * d
             mlp_p = mlp_params(self.d_ff) + d
             return total + n_rec * rec_p + n_att * att_p + self.n_layers * mlp_p
-        return total + n_dec * layer_params()
+        dense = self.moe.first_dense if self.moe else 0
+        return total + dense * layer_params(False) \
+            + (n_dec - dense) * layer_params()
 
     def active_param_count(self) -> int:
         """Active params per token (= param_count for dense)."""
         if not self.moe:
             return self.param_count()
         mo = self.moe
-        inactive = (mo.n_experts - mo.top_k) * 3 * self.d_model * mo.d_ff
-        return self.param_count() - self.n_layers * inactive
+        inactive = (mo.n_held - mo.top_k * mo.n_held / mo.n_experts) \
+            * 3 * self.d_model * mo.d_ff
+        return int(self.param_count()
+                   - (self.n_layers - mo.first_dense) * inactive)
 
 
 @dataclasses.dataclass(frozen=True)

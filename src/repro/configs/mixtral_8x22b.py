@@ -18,16 +18,14 @@ FULL = ModelConfig(
     activation="silu",
     norm_eps=1e-5,
     tie_embeddings=False,
-    moe=MoEConfig(n_experts=8, top_k=2, n_shared=0, d_ff=16384,
-                  capacity_factor=1.25, sharding="tp"),
+    moe=MoEConfig(n_experts=8, top_k=2, n_shared=0, d_ff=16384, sharding="tp"),
     source="arXiv:2401.04088; hf",
 )
 
 SMOKE = FULL.with_(
     name="mixtral-smoke", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
     head_dim=16, d_ff=64, vocab=256, window=16,
-    moe=MoEConfig(n_experts=4, top_k=2, n_shared=0, d_ff=64,
-                  capacity_factor=2.0, sharding="tp"),
+    moe=MoEConfig(n_experts=4, top_k=2, n_shared=0, d_ff=64, sharding="tp"),
     dtype="float32", param_dtype="float32")
 
 register("mixtral-8x22b", FULL, SMOKE)

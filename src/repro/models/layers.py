@@ -100,11 +100,39 @@ def init_rms_norm(d, dtype):
 # --------------------------------------------------------------------------
 # rotary embeddings
 # --------------------------------------------------------------------------
-def rope_angles(positions, dim: int, theta: float):
-    """positions (..., S) -> cos/sin (..., S, dim/2)."""
-    freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * np.log(factor) + 1.0
+
+
+def rope_freqs(dim: int, theta: float, yarn) -> np.ndarray:
+    """The dim/2 YaRN rotary frequencies (``yarn`` a YarnConfig): the
+    original ones up to the beta_fast correction dim, the interpolated
+    ones (/ factor) past the beta_slow one, and a linear ramp between."""
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def corr(rot):
+        return dim * np.log(yarn.original_max_positions
+                            / (rot * 2 * np.pi)) / (2 * np.log(theta))
+
+    lo = max(int(np.floor(corr(yarn.beta_fast))), 0)
+    hi = min(int(np.ceil(corr(yarn.beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    return (base / yarn.factor * ramp + base * (1 - ramp)).astype(np.float32)
+
+
+def rope_angles(positions, dim: int, theta: float, yarn=None):
+    """positions (..., S) -> cos/sin (..., S, dim/2), with YaRN's
+    frequencies and its cos/sin factor where ``yarn`` is given."""
+    if yarn is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
+                                 / dim))
+        ang = positions.astype(jnp.float32)[..., None] * freqs
+        return jnp.cos(ang), jnp.sin(ang)
+    ang = positions.astype(jnp.float32)[..., None] \
+        * jnp.asarray(rope_freqs(dim, theta, yarn))
+    m = np.float32(yarn_mscale(yarn.factor, yarn.mscale)
+                   / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x, positions, cfg: ModelConfig):
@@ -120,7 +148,7 @@ def apply_rope(x, positions, cfg: ModelConfig):
     rot -= rot % 2
     xr, xp = x[..., :rot], x[..., rot:]
     pos = positions  # (..., S)
-    cos, sin = rope_angles(pos, rot, cfg.rope_theta)  # (..., S, rot/2)
+    cos, sin = rope_angles(pos, rot, cfg.rope_theta, cfg.yarn)  # (..., S, rot/2)
     cos = cos[..., :, None, :]
     sin = sin[..., :, None, :]
     if cfg.rope_style == "half":
@@ -341,6 +369,10 @@ def init_mla(key, cfg: ModelConfig, dtype) -> Tuple[Params, Axes]:
     p["wq"], a["wq"] = make_param(ks[0], (d, H, qd), ("embed", "heads", "head_dim"), dtype)
     p["wkv_a"], a["wkv_a"] = make_param(
         ks[1], (d, m.kv_lora_rank + m.rope_head_dim), ("embed", "kv_lora"), dtype)
+    # RMSNorm on the latent before it is cached and decompressed
+    # (DeepSeek's kv_a_layernorm).
+    p["kv_norm"], a["kv_norm"] = zeros_param((m.kv_lora_rank,), dtype), \
+        ("kv_lora",)
     p["wkv_b"], a["wkv_b"] = make_param(
         ks[2], (m.kv_lora_rank, H, m.nope_head_dim + m.v_head_dim),
         ("kv_lora", "heads", "head_dim"), dtype)
@@ -349,30 +381,51 @@ def init_mla(key, cfg: ModelConfig, dtype) -> Tuple[Params, Axes]:
     return p, a
 
 
-def mla_attention(params: Params, cfg: ModelConfig, x, positions,
-                  cache: Optional[Params] = None):
-    """MLA: KV compressed to a per-token latent (kv_lora_rank) + a shared
-    rope key.  The decode cache stores only the latent + rope key — the
-    memory saving that is MLA's point."""
+def mla_mscale2(cfg: ModelConfig) -> float:
+    """YaRN's factor on the attention softmax scale, mscale ** 2."""
+    y = cfg.yarn
+    if y is None or not y.mscale_all_dim:
+        return 1.0
+    return float(yarn_mscale(y.factor, y.mscale_all_dim) ** 2)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope head dim) ** -0.5, times YaRN's mscale ** 2."""
     m = cfg.mla
-    H = cfg.n_heads
-    B, S, _ = x.shape
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
-    q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    rcfg = cfg.with_(rope_style="half", rope_fraction=1.0)
-    q_rope = apply_rope(q_rope, positions, rcfg)
-    kv_a = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
-    c_kv, k_rope = kv_a[..., :m.kv_lora_rank], kv_a[..., m.kv_lora_rank:]
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, rcfg)[:, :, 0]
-    new_cache = None
-    if cache is not None:
-        idx = cache["idx"]
-        c_kv = lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), idx, 1)
-        k_rope = lax.dynamic_update_slice_in_dim(cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), idx, 1)
-        new_cache = {"c_kv": c_kv, "k_rope": k_rope, "idx": idx + S}
-        k_positions = jnp.arange(c_kv.shape[1])
-    else:
-        k_positions = positions if positions.ndim == 1 else positions[0]
+    return (m.nope_head_dim + m.rope_head_dim) ** -0.5 * mla_mscale2(cfg)
+
+
+def _mla_latent_decode(params: Params, cfg: ModelConfig, q_nope, q_rope,
+                       c_kv, k_rope, q_pos):
+    """One query position against the cached latent, in latent space:
+    ``wkv_b``'s key half is absorbed into the query, which scores the
+    normalised latent (plus the shared rope key); the weighted latent then
+    goes through ``wkv_b``'s value half.  The cache is never decompressed.
+    q_nope (B,1,H,dn), q_rope (B,1,H,dr), c_kv (B,T,r), k_rope (B,T,dr)."""
+    m = cfg.mla
+    wk = params["wkv_b"][..., :m.nope_head_dim]            # (r, H, dn)
+    wv = params["wkv_b"][..., m.nope_head_dim:]            # (r, H, dv)
+    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, wk,
+                       preferred_element_type=jnp.float32).astype(c_kv.dtype)
+    scores = (jnp.einsum("bshr,btr->bhst", q_lat, c_kv,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bshk,btk->bhst", q_rope, k_rope,
+                           preferred_element_type=jnp.float32))
+    scores = scores * mla_softmax_scale(cfg)
+    visible = jnp.arange(c_kv.shape[1]) <= q_pos[-1]
+    scores = jnp.where(visible, scores, -1e30)
+    w = jax.nn.softmax(scores, axis=-1).astype(c_kv.dtype)
+    o_lat = jnp.einsum("bhst,btr->bshr", w, c_kv,
+                       preferred_element_type=jnp.float32).astype(c_kv.dtype)
+    return jnp.einsum("bshr,rhk->bshk", o_lat, wv)
+
+
+def _mla_decompressed(params: Params, cfg: ModelConfig, q_nope, q_rope,
+                      c_kv, k_rope, q_pos, k_positions):
+    """Queries against keys and values decompressed from the latent
+    through ``wkv_b``, at full rank (the prompt path).  Returns the heads'
+    values, (B, S, H, dv)."""
+    m = cfg.mla
     kv = jnp.einsum("bsr,rhk->bshk", c_kv, params["wkv_b"])
     k_nope, v = kv[..., :m.nope_head_dim], kv[..., m.nope_head_dim:]
     # assemble full-rank q/k with the shared rope key broadcast over heads
@@ -380,20 +433,58 @@ def mla_attention(params: Params, cfg: ModelConfig, x, positions,
                                 (*k_nope.shape[:3], m.rope_head_dim))
     k_full = jnp.concatenate([k_nope, k_rope_b], axis=-1)
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    q_pos = positions if positions.ndim == 1 else positions[0]
-    use_chunked = (S * k_full.shape[1] > 1024 * 1024)
+    # the attention kernels scale by qd ** -0.5; YaRN's mscale ** 2 rides
+    # on the query
+    if mla_mscale2(cfg) != 1.0:
+        q_full = (q_full.astype(jnp.float32) * mla_mscale2(cfg)
+                  ).astype(q_full.dtype)
+    use_chunked = (q_full.shape[1] * k_full.shape[1] > 1024 * 1024)
     fn = chunked_attention if use_chunked else naive_attention
     # pad v to match head dims for the shared kernel, slice after
     pad = q_full.shape[-1] - v.shape[-1]
     v_p = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-    out = fn(q_full, k_full, v_p, causal=cfg.causal, window=cfg.window,
-             q_positions=q_pos, k_positions=k_positions,
-             softcap=cfg.attn_logit_softcap,
-             **({"q_block": cfg.attn_q_block, "k_block": cfg.attn_k_block,
-                 "unroll": cfg.probe_unroll}
-                if fn is chunked_attention else {}))[..., :m.v_head_dim]
-    out = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
-    return out, new_cache
+    return fn(q_full, k_full, v_p, causal=cfg.causal, window=cfg.window,
+              q_positions=q_pos, k_positions=k_positions,
+              softcap=cfg.attn_logit_softcap,
+              **({"q_block": cfg.attn_q_block, "k_block": cfg.attn_k_block,
+                  "unroll": cfg.probe_unroll}
+                 if fn is chunked_attention else {}))[..., :m.v_head_dim]
+
+
+def mla_attention(params: Params, cfg: ModelConfig, x, positions,
+                  cache: Optional[Params] = None):
+    """MLA: KV compressed to a per-token latent (kv_lora_rank) + a shared
+    rope key.  The decode cache stores only the (normalised) latent + rope
+    key — the memory saving that is MLA's point.  A single query position
+    against a cache attends in latent space (:func:`_mla_latent_decode`);
+    a prompt, with or without a cache, decompresses keys and values
+    through ``wkv_b``."""
+    m = cfg.mla
+    B, S, _ = x.shape
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+    q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
+    rcfg = cfg.with_(rope_style="half", rope_fraction=1.0)
+    q_rope = apply_rope(q_rope, positions, rcfg)
+    kv_a = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
+    c_kv, k_rope = kv_a[..., :m.kv_lora_rank], kv_a[..., m.kv_lora_rank:]
+    c_kv = rms_norm(c_kv, params["kv_norm"], cfg.norm_eps)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, rcfg)[:, :, 0]
+    q_pos = positions if positions.ndim == 1 else positions[0]
+    new_cache = None
+    if cache is not None:
+        idx = cache["idx"]
+        c_kv = lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), idx, 1)
+        k_rope = lax.dynamic_update_slice_in_dim(cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), idx, 1)
+        new_cache = {"c_kv": c_kv, "k_rope": k_rope, "idx": idx + S}
+    if cache is not None and S == 1:
+        out = _mla_latent_decode(params, cfg, q_nope, q_rope, c_kv, k_rope,
+                                 q_pos)
+    else:
+        k_positions = jnp.arange(c_kv.shape[1]) if cache is not None \
+            else q_pos
+        out = _mla_decompressed(params, cfg, q_nope, q_rope, c_kv, k_rope,
+                                q_pos, k_positions)
+    return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), new_cache
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):

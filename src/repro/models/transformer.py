@@ -32,7 +32,9 @@ Params = Dict[str, Any]
 # --------------------------------------------------------------------------
 # init
 # --------------------------------------------------------------------------
-def _init_dense_layer(key, cfg: ModelConfig, dtype):
+def _init_dense_layer(key, cfg: ModelConfig, dtype, moe: bool = True):
+    """One attention layer with the config's experts (``moe``) or with a
+    SwiGLU of ``cfg.d_ff``."""
     ks = jax.random.split(key, 4)
     p, a = {}, {}
     p["ln1"], a["ln1"] = init_rms_norm(cfg.d_model, dtype)
@@ -41,11 +43,17 @@ def _init_dense_layer(key, cfg: ModelConfig, dtype):
         p["attn"], a["attn"] = init_mla(ks[0], cfg, dtype)
     else:
         p["attn"], a["attn"] = init_attention(ks[0], cfg, dtype)
-    if cfg.moe is not None:
+    if cfg.moe is not None and moe:
         p["moe"], a["moe"] = moe_mod.init_moe(ks[1], cfg, dtype)
     else:
         p["mlp"], a["mlp"] = init_mlp(ks[1], cfg.d_model, cfg.d_ff, dtype)
     return p, a
+
+
+def n_dense_layers(cfg: ModelConfig) -> int:
+    """Leading dense layers that sit before an MoE config's expert stack
+    (``params["dense"]``); the rest are ``params["layers"]``."""
+    return cfg.moe.first_dense if cfg.moe is not None else 0
 
 
 def _init_rwkv_layer(key, cfg: ModelConfig, dtype):
@@ -98,8 +106,14 @@ def init(cfg: ModelConfig, key) -> Tuple[Params, Params]:
     p, a = {}, {}
     p["embed"], a["embed"] = init_embed(k_embed, cfg, dtype)
     if cfg.family in ("dense", "moe", "vlm", "audio"):
+        n_dense = n_dense_layers(cfg)
+        if n_dense:
+            p["dense"], a["dense"] = _stack_init(
+                lambda k: _init_dense_layer(k, cfg, dtype, moe=False),
+                k_tail, n_dense)
         p["layers"], a["layers"] = _stack_init(
-            lambda k: _init_dense_layer(k, cfg, dtype), k_layers, cfg.n_layers)
+            lambda k: _init_dense_layer(k, cfg, dtype), k_layers,
+            cfg.n_layers - n_dense)
     elif cfg.family == "ssm":
         p["layers"], a["layers"] = _stack_init(
             lambda k: _init_rwkv_layer(k, cfg, dtype), k_layers, cfg.n_layers)
@@ -160,7 +174,7 @@ def _dense_block(cfg: ModelConfig, carry, lp, positions):
         h, _ = attention(lp["attn"], cfg, h, positions)
     x = constrain(x + h, ("batch", "seq", "act_embed"))
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.moe is not None:
+    if "moe" in lp:
         h, a, counts = moe_mod.moe_block(lp["moe"], cfg, h)
         aux = aux + a
     else:
@@ -212,7 +226,11 @@ def forward(params: Params, cfg: ModelConfig, tokens,
     if cfg.family in ("dense", "moe", "vlm", "audio"):
         block = _maybe_remat(
             lambda c, lp: _dense_block(cfg, c, lp, positions), cfg)
-        (x, aux), counts = lax.scan(block, (x, aux0), params["layers"],
+        carry = (x, aux0)
+        if "dense" in params:
+            carry, _ = lax.scan(block, carry, params["dense"],
+                                unroll=cfg.probe_unroll)
+        (x, aux), counts = lax.scan(block, carry, params["layers"],
                                     unroll=cfg.probe_unroll)
         info["aux"] = aux
         info["expert_counts"] = counts  # (L, E) per-layer expert loads
@@ -322,10 +340,21 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int) -> Params:
             one = lambda: init_mla_cache(cfg, batch, max_len, dtype)
         else:
             one = lambda: init_attention_cache(cfg, batch, max_len, dtype)
-        caches = jax.tree.map(
-            lambda *xs: jnp.stack(xs),
-            *[one() for _ in range(cfg.n_layers)])
-        return {"layers": caches}
+
+        def stack(n):
+            return jax.tree.map(lambda *xs: jnp.stack(xs),
+                                *[one() for _ in range(n)])
+
+        n_dense = n_dense_layers(cfg)
+        state = {"layers": stack(cfg.n_layers - n_dense)}
+        if n_dense:
+            state["dense"] = stack(n_dense)
+        if cfg.moe is not None:
+            # the routes each expert received in the call that produced
+            # this state, per expert layer
+            state["expert_counts"] = jnp.zeros(
+                (cfg.n_layers - n_dense, cfg.moe.n_experts), jnp.int32)
+        return state
     if cfg.family == "ssm":
         dh = cfg.recurrent.head_dim
         H = cfg.d_model // dh
@@ -366,9 +395,7 @@ def decode_step(params: Params, cfg: ModelConfig, state: Params,
     positions = pos[None] if pos.ndim == 0 else pos
     x = constrain(x, ("batch", "seq", "act_embed"))
     if cfg.family in ("dense", "moe", "vlm", "audio"):
-        def block(carry, inp):
-            xx, aux = carry
-            lp, cache = inp
+        def attend(xx, lp, cache):
             h = rms_norm(xx, lp["ln1"], cfg.norm_eps)
             if cfg.mla is not None:
                 h, new_cache = mla_attention(lp["attn"], cfg, h, positions,
@@ -377,18 +404,46 @@ def decode_step(params: Params, cfg: ModelConfig, state: Params,
                 h, new_cache = attention(lp["attn"], cfg, h, positions,
                                          cache=cache)
             xx = xx + h
-            h = rms_norm(xx, lp["ln2"], cfg.norm_eps)
-            if cfg.moe is not None:
-                h, a, _ = moe_mod.moe_block(lp["moe"], cfg, h)
-                aux = aux + a
-            else:
-                h = mlp(lp["mlp"], h, cfg.activation)
+            return xx, rms_norm(xx, lp["ln2"], cfg.norm_eps), new_cache
+
+        def block(carry, inp):
+            xx, aux = carry
+            lp, cache = inp
+            xx, h, new_cache = attend(xx, lp, cache)
+            h = mlp(lp["mlp"], h, cfg.activation)
             return (xx + h, aux), new_cache
 
-        (x, _), new_caches = lax.scan(
-            block, (x, jnp.zeros((), jnp.float32)),
-            (params["layers"], state["layers"]), unroll=cfg.probe_unroll)
-        new_state = {"layers": new_caches}
+        carry = (x, jnp.zeros((), jnp.float32))
+        new_state = {}
+        if "dense" in params:
+            carry, new_state["dense"] = lax.scan(
+                block, carry, (params["dense"], state["dense"]),
+                unroll=cfg.probe_unroll)
+        if cfg.moe is None:
+            carry, new_state["layers"] = lax.scan(
+                block, carry, (params["layers"], state["layers"]),
+                unroll=cfg.probe_unroll)
+        else:
+            # The expert weights stay out of the scanned slices: each
+            # layer reads its experts from the stack by layer index.
+            moe_p = dict(params["layers"]["moe"])
+            experts = {k: moe_p.pop(k) for k in moe_mod.EXPERT_KEYS}
+            rest = {**params["layers"], "moe": moe_p}
+
+            def moe_layer(carry, inp):
+                xx, aux = carry
+                layer, lp, cache = inp
+                xx, h, new_cache = attend(xx, lp, cache)
+                h, a, counts = moe_mod.moe_block({**lp["moe"], **experts},
+                                                 cfg, h, layer=layer)
+                return (xx + h, aux + a), (new_cache, counts)
+
+            n_moe = cfg.n_layers - n_dense_layers(cfg)
+            carry, (new_state["layers"], new_state["expert_counts"]) = \
+                lax.scan(moe_layer, carry,
+                         (jnp.arange(n_moe), rest, state["layers"]),
+                         unroll=cfg.probe_unroll)
+        x = carry[0]
     elif cfg.family == "ssm":
         def block(xx, inp):
             lp, st = inp

@@ -12,7 +12,7 @@ region tree::
     ├── decode         one generated token per busy lane per step
     ├── kv_append      KV-cache slot writes (VMEM_PRESSURE = occupancy)
     ├── sample         logits -> token selection
-    └── moe            (MoE configs) router + expert_0..E-1 children
+    └── moe            (MoE configs) router + one child per held expert
 
 "Per-batch-lane leaves" are realized on the trace's *process axis*: lane
 ``i`` is process ``i``, exactly the SPMD mapping the analyzer's
@@ -54,11 +54,13 @@ SAMPLE = "sample"
 MOE = "moe"
 
 
-def serve_region_tree(moe_experts: int = 0, name: str = "serve") -> RegionTree:
+def serve_region_tree(moe_experts: int = 0, name: str = "serve",
+                      first_expert: int = 0) -> RegionTree:
     """The serving region tree.  With ``moe_experts`` > 0 an inclusive
-    ``moe`` parent (router + experts) gets one child per expert, the
-    same layout the train-side expert probe uses, so hot-expert verdicts
-    localize to ``serve/moe/expert_e``."""
+    ``moe`` parent (router + experts) gets one child per expert,
+    ``expert_<first_expert>`` on (the block of experts a layer holds),
+    the same layout the train-side expert probe uses, so hot-expert
+    verdicts localize to ``serve/moe/expert_e``."""
     tree = RegionTree(name)
     tree.add(PREFILL)
     tree.add(DECODE)
@@ -66,7 +68,7 @@ def serve_region_tree(moe_experts: int = 0, name: str = "serve") -> RegionTree:
     tree.add(SAMPLE)
     if moe_experts:
         moe = tree.add(MOE)
-        for e in range(moe_experts):
+        for e in range(first_expert, first_expert + moe_experts):
             tree.add(f"expert_{e}", parent=moe)
     return tree
 

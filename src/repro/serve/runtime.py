@@ -25,6 +25,15 @@ append), so the region carries the appended bytes
 cache occupancy as VMEM_PRESSURE, with ~zero wall — exactly the signals
 the KV archetypes condition on.  ``sample`` is a separately jitted,
 separately timed argmax.
+
+On MoE configs the tree has a ``moe`` parent with one ``expert_e`` child
+per expert the layer holds, filled from the real routing: each prefill
+or decode call returns its per-layer routed-token counts in the decode
+state, pulled once the call's result is ready (no extra wait), and
+``expert_e`` carries that expert's routes as FLOPS (the three expert
+matmuls per route) and, as BYTES, one read of its weights per layer that
+routed to it — quantities, like ``kv_append``, with no wall of their own
+(the experts run fused inside the decode call).
 """
 from __future__ import annotations
 
@@ -45,7 +54,7 @@ from repro.core.trace import RegionTrace
 from repro.models import ModelApi, encdec
 from repro.scenarios.traffic import prompt_tokens
 
-from .engine import DECODE, KV_APPEND, PREFILL, SAMPLE, LaneEvent, \
+from .engine import DECODE, KV_APPEND, MOE, PREFILL, SAMPLE, LaneEvent, \
     serve_region_tree
 
 CHUNK_FAMILIES = ("dense", "moe", "vlm", "audio")
@@ -78,13 +87,24 @@ class JitBackend:
         self.prefill_chunk = prefill_chunk
         self.seed = seed
         self.embeds_fn = embeds_fn
-        self.tree = serve_region_tree()
+        mo = cfg.moe
+        self.tree = serve_region_tree(
+            moe_experts=mo.n_held if mo else 0,
+            first_expert=mo.first_held if mo else 0)
         self.region_ids = [r.region_id for r in self.tree.regions()]
         root = self.tree.root.name
         self._rid = {p: self.tree.by_path(f"{root}/{p}").region_id
-                     for p in (PREFILL, DECODE, KV_APPEND, SAMPLE)}
+                     for p in (PREFILL, DECODE, KV_APPEND, SAMPLE)
+                     + ((MOE,) if mo else ())}
+        self._expert_rids = [
+            self.tree.by_path(f"{root}/{MOE}/expert_{e}").region_id
+            for e in range(mo.first_held, mo.first_held + mo.n_held)
+        ] if mo else []
         self._decode = jax.jit(
             lambda p, s, t, pos: api.decode_step(p, s, t, pos))
+        # A lane's fresh decode state in one compiled call: built op by op
+        # it costs tens of milliseconds of host dispatch per request.
+        self._init_state = jax.jit(lambda: api.init_decode_state(1, max_len))
         # The sampler also reports whether the logits row it sampled was
         # finite: argmax of a row holding NaN still returns a token.
         self._sample = jax.jit(
@@ -105,8 +125,18 @@ class JitBackend:
         self._decode_costs: Dict[int, Tuple[float, float]] = {}
         self._sample_cost: Optional[Tuple[float, float]] = None
         dt = np.dtype(cfg.activation_dtype())
-        self.kv_bytes_per_token = (2 * cfg.n_layers * cfg.n_kv_heads
-                                   * cfg.resolved_head_dim * dt.itemsize)
+        if cfg.mla is not None:     # the latent and the shared rope key
+            self.kv_bytes_per_token = (cfg.n_layers * dt.itemsize * (
+                cfg.mla.kv_lora_rank + cfg.mla.rope_head_dim))
+        else:
+            self.kv_bytes_per_token = (2 * cfg.n_layers * cfg.n_kv_heads
+                                       * cfg.resolved_head_dim * dt.itemsize)
+        # An expert's three matmuls: operations per route, weight bytes.
+        self._expert_flops = 6.0 * cfg.d_model * mo.d_ff if mo else 0.0
+        self._expert_bytes = (3.0 * cfg.d_model * mo.d_ff
+                              * np.dtype(cfg.parameter_dtype()).itemsize
+                              if mo else 0.0)
+        self._dispatch = None
         if JitBackend._cpu_clock is None:
             JitBackend._cpu_clock = _pick_cpu_clock()
         self._clock, self._tick, self._clock_name = JitBackend._cpu_clock
@@ -119,7 +149,7 @@ class JitBackend:
             return self.api.init_decode_state(1, self.max_len,
                                               params=self.params,
                                               enc_out=enc_out)
-        return self.api.init_decode_state(1, self.max_len)
+        return self._init_state()
 
     def _costs_for(self, tokens, pos, state) -> Tuple[float, float]:
         k = int(tokens.shape[1])
@@ -130,10 +160,10 @@ class JitBackend:
         return self._decode_costs[k]
 
     def warmup(self) -> None:
-        """Compile (and discard) the two steady-state decode shapes and
-        the sampler — excluded from every reported timing."""
-        state = self.api.init_decode_state(1, self.max_len) \
-            if self.cfg.family != "encdec" else None
+        """Compile (and discard) the fresh lane state, the two
+        steady-state decode shapes and the sampler — excluded from every
+        reported timing."""
+        state = self._init_state() if self.cfg.family != "encdec" else None
         if state is None:
             return  # encdec compiles per request state; first call warms
         shapes = {1}
@@ -160,7 +190,7 @@ class JitBackend:
         (until the device is done), labelled by :attr:`_call`."""
         t0w = time.perf_counter()
         t0c = self._clock()
-        with span("serve.dispatch", **self._call):
+        with span("serve.dispatch", **self._call) as self._dispatch:
             out = fn(*args)
         with span("serve.wait", **self._call):
             jax.block_until_ready(out)
@@ -196,6 +226,7 @@ class JitBackend:
                 if a + k == req.prompt_len:
                     self._pending_logits[lane] = logits
                 self._write(tr, PREFILL, lane, dw, dc, fl, by)
+                self._experts(tr, lane, new_state, k)
             if ev.decode_tokens:
                 # Sample the pending logits (its own timed region), then
                 # feed the sampled token to produce the next logits.
@@ -215,6 +246,7 @@ class JitBackend:
                 self._state[lane] = new_state
                 self._pending_logits[lane] = logits
                 self._write(tr, DECODE, lane, dw, dc, fl, by)
+                self._experts(tr, lane, new_state, 1)
             if ev.kv_tokens:
                 # The KV write is fused into the decode kernel here, so
                 # this region carries quantities, not time: appended
@@ -228,6 +260,28 @@ class JitBackend:
                 self._pending_logits[lane] = None
                 self._prompt[lane] = None
         return tr
+
+    def _experts(self, tr: RegionTrace, lane: int, state, tokens: int
+                 ) -> None:
+        """The call's routed-token counts into the held experts' regions
+        and onto its ``serve.dispatch`` span: ``routes_held`` (routes to
+        held experts) and ``experts_hit`` (held experts with a route),
+        summed over the expert layers."""
+        if not self._expert_rids:
+            return
+        mo = self.cfg.moe
+        counts = np.asarray(state["expert_counts"])      # (layers, E)
+        held = counts[:, mo.first_held:mo.first_held + mo.n_held]
+        routes, hit = held.sum(axis=0), (held > 0).sum(axis=0)
+        self._dispatch.set(tokens=tokens, routes_held=int(routes.sum()),
+                           experts_hit=int(hit.sum()))
+        fl, by = tr.metric(FLOPS)[0, 0, lane], tr.metric(BYTES)[0, 0, lane]
+        for rid, r, h in zip(self._expert_rids, routes, hit):
+            fl[tr.col(rid)] += r * self._expert_flops
+            by[tr.col(rid)] += h * self._expert_bytes
+        j = tr.col(self._rid[MOE])
+        fl[j] += routes.sum() * self._expert_flops
+        by[j] += hit.sum() * self._expert_bytes
 
     def _write(self, tr: RegionTrace, phase: str, lane: int,
                wall: float, cpu: float, fl: float, by: float) -> None:

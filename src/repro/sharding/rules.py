@@ -52,7 +52,6 @@ ACT_RULES: Rules = {
     "act_kv": "model",        # KV-cache head dim (decode memory fit)
     "act_mlp": "model",
     "expert": "model",
-    "capacity": "data",
     "vocab_out": "model",
 }
 

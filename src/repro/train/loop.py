@@ -63,7 +63,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig) -> Callable:
 
 
 def _expert_probe_leaf(cfg: ModelConfig, expert: int):
-    """A per-expert instrumented region: run expert ``expert``'s gated FFN
+    """A per-expert instrumented region: run held expert ``expert``'s FFN
     (layer 0 weights from the live params) on the shard's probe-token
     tile, ``bundle["expert_iters"][expert]`` times — so a hot expert
     genuinely executes more jitted work, per shard, inside its own region.
@@ -162,8 +162,8 @@ def train_region_tree(cfg: ModelConfig, opt_cfg: AdamWConfig,
                 return fwd_bwd(state, bundle["batch"])
         tree.add("fwd_bwd", fn=fwd_bwd_leaf)
         moe_parent = tree.add("moe")
-        for e in range(cfg.moe.n_experts):
-            tree.add(f"expert_{e}", parent=moe_parent,
+        for e in range(cfg.moe.n_held):
+            tree.add(f"expert_{cfg.moe.first_held + e}", parent=moe_parent,
                      fn=_expert_probe_leaf(cfg, e))
 
         def optimizer_leaf(state, bundle):
@@ -324,7 +324,7 @@ class Trainer:
                 # shard count is checked in TrainerConfig; the expert
                 # count needs the model config, so it is checked here
                 # (train_region_tree rejects the non-MoE case itself)
-                want = self.cfg.moe.n_experts
+                want = self.cfg.moe.n_held
                 for i, row in enumerate(self.tcfg.trace_expert_iters):
                     if len(row) != want:
                         raise ValueError(

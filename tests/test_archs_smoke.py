@@ -83,7 +83,7 @@ def test_full_config_matches_assignment(arch):
         "mistral-nemo-12b": (40, 5120, 32, 8, 14336, 131072),
         "gemma-7b": (28, 3072, 16, 16, 24576, 256000),
         "phi-3-vision-4.2b": (32, 3072, 32, 32, 8192, 32064),
-        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 1408, 102400),
+        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102400),
         "mixtral-8x22b": (56, 6144, 48, 8, 16384, 32768),
         "rwkv6-3b": (32, 2560, 40, 40, 8960, 65536),
         "seamless-m4t-medium": (12, 1024, 16, 16, 4096, 256206),

@@ -1,5 +1,6 @@
-"""MoE dispatch correctness: sort-based capacity dispatch vs a dense
-per-token reference, load counts, aux loss, and capacity drops."""
+"""MoE dispatch correctness: the dropless expert layer vs a dense
+per-token reference, load counts, aux loss, and no token dropped however
+the router skews."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +12,7 @@ from repro.models.moe import init_moe, moe_block
 
 
 def dense_moe_reference(params, cfg, x):
-    """Per-token loop over its top-k experts (no capacity)."""
+    """Per-token loop over its top-k experts."""
     mo = cfg.moe
     B, S, D = x.shape
     N = B * S
@@ -39,7 +40,7 @@ def dense_moe_reference(params, cfg, x):
 def setup():
     cfg = get_arch("mixtral-8x22b").smoke.with_(
         moe=MoEConfig(n_experts=4, top_k=2, n_shared=0, d_ff=32,
-                      capacity_factor=8.0, sharding="tp"))
+                      sharding="tp"))
     params, _ = init_moe(jax.random.key(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.key(1), (2, 8, cfg.d_model))
     return cfg, params, x
@@ -63,12 +64,42 @@ class TestMoE:
         _, aux, _ = moe_block(params, cfg, x)
         assert np.isfinite(float(aux)) and float(aux) > 0
 
-    def test_capacity_drops_tokens(self, setup):
+    def test_no_token_dropped_when_every_token_picks_the_same_experts(
+            self, setup):
+        """A router biased so that all 16 tokens route to experts 1 and 3
+        (every token carries a large first feature, which the router
+        scores for those two): each takes every token, and the output is
+        still the per-token reference's (a capacity-bounded layer would
+        drop most)."""
         cfg, params, x = setup
-        y_full, _, _ = moe_block(params, cfg, x)
-        y_cap, _, _ = moe_block(params, cfg, x, capacity=1)
-        # with capacity 1 most tokens are dropped -> outputs differ
-        assert float(jnp.abs(y_full - y_cap).max()) > 1e-3
+        x = x.at[..., 0].set(10.0)
+        params = dict(params, router=params["router"].at[0, jnp.array(
+            [1, 3])].add(5.0))
+        y, _, counts = moe_block(params, cfg, x)
+        assert counts.tolist() == [0, 16, 0, 16]
+        ref = dense_moe_reference(params, cfg, x)
+        np.testing.assert_allclose(np.asarray(y), ref, atol=2e-4, rtol=2e-4)
+
+    def test_grouped_products_train_as_the_every_expert_loop(self, setup):
+        """From ``GROUPED_TOKENS`` tokens on the layer sorts its routes
+        through grouped products: output and gradients (weights and
+        input, aux loss included) are the every-expert loop's."""
+        from repro.models import moe as moe_mod
+        cfg, params, _ = setup
+        x = jax.random.normal(jax.random.key(2),
+                              (2, moe_mod.GROUPED_TOKENS // 2, cfg.d_model))
+
+        def loss(p, x):
+            y, aux, _ = moe_block(p, cfg, x)
+            return jnp.mean(jnp.square(y)) + aux
+
+        grouped = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe_mod, "GROUPED_TOKENS", 1 << 30)
+            loop = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+        for a, b in zip(jax.tree.leaves(grouped), jax.tree.leaves(loop)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-6)
 
     def test_shared_experts_added(self):
         cfg = get_arch("deepseek-v2-lite-16b").smoke

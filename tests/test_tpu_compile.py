@@ -102,3 +102,37 @@ def test_full_width_decode_step_fits_one_chip(one_chip, tokens):
     assert mem.argument_size_in_bytes < HBM_BYTES
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("tokens", [1, 256])
+def test_expert_share_decode_step_reads_the_stack_in_place(one_chip, tokens):
+    """DeepSeek-V2-Lite at one chip's share of an EP4 host (16 of 64
+    experts, 4.91 B parameters, 9.82 GB in bf16) over a 4096-position
+    latent cache, as JitBackend calls it at 1 token and at a prefill
+    chunk of 256.  The temporaries stay small: neither the guarded
+    per-expert products of a decode call nor the held experts of a
+    prefill chunk copy a layer's 277 MB block of experts out of the
+    stack."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.models import build
+    full = get_arch("deepseek-v2-lite-16b").full
+    cfg = full.with_(moe=dataclasses.replace(full.moe, held=16))
+    api = build(cfg)
+    place = lambda tree: jax.tree.map(
+        lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+    params = place(jax.eval_shape(lambda key: api.init(key)[0],
+                                  jax.random.key(0)))
+    state = place(jax.eval_shape(lambda: api.init_decode_state(1, 4096)))
+    toks = _spec(one_chip, (1, tokens), jnp.int32)
+    pos = _spec(one_chip, (tokens,) if tokens > 1 else (), jnp.int32)
+    n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
+    assert round(n_params / 1e9, 2) == 4.91
+    compiled = jax.jit(api.decode_step).lower(params, state, toks,
+                                              pos).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64e6
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
+    assert ("conditional(" in compiled.as_text()) == (tokens == 1)
