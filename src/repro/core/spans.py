@@ -18,8 +18,11 @@ work happens::
         trace = load(path)
         s.set(bytes=nbytes)
 
-A span never goes inside a per-pair or per-element loop: count the loop's
-work and set it after the loop.
+A callee that does not know which span its caller opened counts its work
+into it with :func:`annotate` (``RegionTrace.save`` and ``load`` count
+their stored and inflated bytes so).  A span never goes inside a
+per-pair or per-element loop: count the loop's work and set it after the
+loop.
 """
 from __future__ import annotations
 
@@ -76,8 +79,8 @@ class _Open:
     def __enter__(self):
         stack = self.rec._stack()
         self.id = next(self.rec._ids)
-        self.parent = stack[-1] if stack else None
-        stack.append(self.id)
+        self.parent = stack[-1].id if stack else None
+        stack.append(self)
         self.ann = TraceAnnotation(PREFIX + self.name)
         self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
@@ -103,7 +106,7 @@ class Recorder:
         self._origin: Optional[int] = None
         self._dropped = 0
 
-    def _stack(self) -> List[int]:
+    def _stack(self) -> List["_Open"]:
         try:
             return self._local.stack
         except AttributeError:
@@ -126,6 +129,14 @@ class Recorder:
             return _OFF
         return _Open(self, name, attrs)
 
+    def annotate(self, **attrs) -> None:
+        """Set attributes on the innermost span open in this thread, if
+        any: how a callee counts its work into whatever span its caller
+        opened around it."""
+        stack = self._stack()
+        if stack:
+            stack[-1].set(**attrs)
+
     def take(self) -> Dict[str, Any]:
         """``{"origin_ns", "spans", "dropped"}`` since the last take, and
         clear them.  ``origin_ns`` is the ``perf_counter_ns`` reading at
@@ -139,4 +150,5 @@ class Recorder:
 
 _RECORDER = Recorder()
 span = _RECORDER.span
+annotate = _RECORDER.annotate
 take = _RECORDER.take

@@ -25,14 +25,19 @@ deterministic reduction the collectors used to fuse inline:
 
 Artifact format (versioned): a single ``.npz`` file holding a JSON
 header under ``__header__`` (version, shape, region schema, meta) and
-one array per metric under ``metric:<name>``.  float64 round-trips
-bit-exactly, so save -> load -> reduce() equals the direct in-memory
-path (tests/test_trace.py).
+one array per metric under ``metric:<name>``.  Each member is deflated
+only where deflate pays (:func:`deflate_pays`) and stored otherwise;
+either kind is a plain zip member that ``np.load`` reads.  float64
+round-trips bit-exactly, so save -> load -> reduce() equals the direct
+in-memory path (tests/test_trace.py).
 """
 from __future__ import annotations
 
 import dataclasses
+import io
 import json
+import zipfile
+import zlib
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -40,8 +45,18 @@ import numpy as np
 from .metrics import (BYTES, COMM_BYTES, CPU_TIME, HBM_INTENSITY,
                       VMEM_PRESSURE, WALL_TIME, RegionMetrics)
 from .regions import CodeRegion, RegionTree
+from .spans import annotate
 
 TRACE_FORMAT_VERSION = 1
+
+# A member is deflated where this many of its first bytes deflate to at
+# most half.  Noisy float64 timings shrink by a few percent and would
+# cost a full inflate on every load; constant or sparse metrics and the
+# JSON header shrink to almost nothing.
+DEFLATE_PROBE = 1 << 16
+# numpy's own .npz writer leaves every member at the zip epoch, which
+# keeps the artifact a function of its contents.
+_ZIP_EPOCH = (1980, 1, 1, 0, 0, 0)
 
 
 class TraceFormatError(ValueError):
@@ -70,6 +85,14 @@ RATE_METRICS = frozenset({VMEM_PRESSURE, HBM_INTENSITY})
 # Timing metrics reduced by min-of-repeats.  Non-timing samples are
 # constant across repeats by construction; min is an exact, deterministic
 # choice for them too, so one rule covers every metric.
+
+
+def deflate_pays(data: bytes) -> bool:
+    """Whether a member's bytes are worth deflating: their first
+    :data:`DEFLATE_PROBE` bytes (all of them, if fewer) deflate to at
+    most half."""
+    probe = data[:DEFLATE_PROBE]
+    return 2 * len(zlib.compress(probe)) <= len(probe)
 
 
 def schema_from_tree(tree: RegionTree) -> List[Dict[str, Any]]:
@@ -277,14 +300,18 @@ class RegionTrace:
     # -- artifact I/O ------------------------------------------------------
     def save(self, path: str) -> str:
         """Write the compact artifact: JSON header + one array per metric
-        inside a single ``.npz``.
+        inside a single ``.npz``, each member deflated where
+        :func:`deflate_pays` and stored otherwise.
 
         Canonical and deterministic: members are written in sorted metric
-        order (not dict insertion order) and ``np.savez_compressed`` pins
-        zip timestamps — so any two traces holding the same samples and
-        header produce the same bytes, which is what lets a streamed
-        spool :meth:`~repro.stream.SpooledTrace.finalize` byte-identically
-        to the monolithic save of the same run."""
+        order (not dict insertion order), each member's encoding is a
+        function of its bytes and every zip timestamp is the zip epoch —
+        so any two traces holding the same samples and header produce the
+        same bytes, which is what lets a streamed spool
+        :meth:`~repro.stream.SpooledTrace.finalize` byte-identically to
+        the monolithic save of the same run.  The enclosing program span
+        gets ``stored_bytes`` and ``raw_bytes``: the member bytes written
+        stored, and all member bytes."""
         header = {
             "format": "repro.region_trace",
             "version": TRACE_FORMAT_VERSION,
@@ -296,10 +323,22 @@ class RegionTrace:
             "meta": self.meta,
             "metrics": sorted(self.data),
         }
-        payload = {f"metric:{k}": self.data[k] for k in sorted(self.data)}
-        with open(path, "wb") as f:
-            np.savez_compressed(f, __header__=json.dumps(header),
-                                **payload)
+        members = [("__header__", np.asarray(json.dumps(header)))]
+        members += [(f"metric:{k}", self.data[k]) for k in sorted(self.data)]
+        stored = raw = 0
+        with zipfile.ZipFile(path, "w") as zf:
+            for name, arr in members:
+                buf = io.BytesIO()
+                np.lib.format.write_array(buf, arr, allow_pickle=False)
+                data = buf.getbuffer()
+                info = zipfile.ZipInfo(name + ".npy", date_time=_ZIP_EPOCH)
+                if deflate_pays(data):
+                    info.compress_type = zipfile.ZIP_DEFLATED
+                else:
+                    stored += len(data)
+                raw += len(data)
+                zf.writestr(info, data)
+        annotate(stored_bytes=stored, raw_bytes=raw)
         return path
 
     @classmethod
@@ -308,8 +347,11 @@ class RegionTrace:
         member / reason) on truncation, corruption, or a malformed header —
         never a raw ``zipfile``/``zlib``/JSON exception.  A missing file
         still raises ``FileNotFoundError`` (absent and damaged are
-        different failures: recovery quarantines one, not the other)."""
-        import zipfile
+        different failures: recovery quarantines one, not the other).
+        Stored and deflated members both read through ``zipfile``, which
+        checks each member's CRC-32.  The enclosing program span gets
+        ``inflated_bytes`` and ``raw_bytes``: the member bytes that had to
+        be inflated, and all member bytes."""
         try:
             z = np.load(path, allow_pickle=False)
         except FileNotFoundError:
@@ -358,6 +400,11 @@ class RegionTrace:
                     raise TraceFormatError(
                         path, f"corrupt metric member: {e}",
                         member=member) from e
+            infos = z.zip.infolist()
+            annotate(inflated_bytes=sum(
+                i.file_size for i in infos
+                if i.compress_type != zipfile.ZIP_STORED),
+                raw_bytes=sum(i.file_size for i in infos))
         try:
             return cls(region_ids=list(header["region_ids"]),
                        n_processes=header["n_processes"],

@@ -664,9 +664,10 @@ class SpooledTrace:
         Byte-identical to ``RegionTrace.save`` of the producer's own merged
         trace: merge is value-exact concatenation, float64 round-trips
         bit-exactly through segment files, the final meta is replayed from
-        the manifest in producer key order, and ``np.savez_compressed``
-        writes deterministically (fixed zip timestamps) — pinned by
-        tests/test_stream.py for the synthetic and train backends.
+        the manifest in producer key order, and ``RegionTrace.save``
+        writes deterministically (each member's encoding a function of
+        its bytes, fixed zip timestamps) — pinned by tests/test_stream.py
+        for the synthetic and train backends.
 
         Only a complete, never-compacted, hole-free spool can reproduce
         the full artifact; anything else raises."""

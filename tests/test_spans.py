@@ -203,3 +203,32 @@ def test_engine_spans_carry_the_request(recorder, tmp_path):
     assert {s.attrs["kind"] for s in calls} == {"prefill", "decode",
                                                  "sample"}
     assert any(s.name == "spool.flush" for s in got)
+
+
+def test_spool_spans_count_member_bytes(recorder, tmp_path):
+    """``RegionTrace.save`` and ``load`` count their member bytes into the
+    span around them: what a flush wrote stored is what a load of that
+    segment did not inflate."""
+    import numpy as np
+
+    from repro.core import RegionTrace
+
+    rng = np.random.default_rng(5)
+    shape = (1, 1, 64, 3)
+    spool = TraceSpool(str(tmp_path / "sp"), chunk_steps=2)
+    with jax.profiler.trace(str(tmp_path / "tr"), profiler_options=_opts()):
+        for _ in range(4):
+            spool.append(RegionTrace(
+                region_ids=[1, 2, 3], n_processes=64,
+                data={"wall_time": rng.standard_normal(shape),
+                      "vmem_pressure": np.full(shape, 0.5)}))
+        sp = SpooledTrace(str(tmp_path / "sp"))
+        sp.window(0, 4)
+    spans.annotate(raw_bytes=1)             # no open span: nothing to set
+    got = spans.take()["spans"]
+    flushes = [s.attrs for s in got if s.name == "spool.flush"]
+    loads = [s.attrs for s in got if s.name == "spool.load"]
+    assert len(flushes) == len(loads) == 2
+    for f, ld in zip(flushes, loads):
+        assert 0 < f["stored_bytes"] < f["raw_bytes"] == ld["raw_bytes"]
+        assert ld["inflated_bytes"] == f["raw_bytes"] - f["stored_bytes"]

@@ -12,10 +12,13 @@ The contracts this file pins (ISSUE 5):
 * the CPU-clock selection prefers the per-thread clock only when it is
   finer *and* attributable, keeping the measured-tick fallback otherwise.
 """
+import hashlib
 import json
 import os
+import struct
 import subprocess
 import sys
+import zipfile
 
 import numpy as np
 import pytest
@@ -24,8 +27,9 @@ from repro.core import AutoAnalyzer, RegionTrace, TimedRegionRunner
 from repro.core import collector as collector_mod
 from repro.core.analyzer import Verdict
 from repro.scenarios.corpus import CORPUS
+from repro.core.trace import TraceFormatError
 from repro.stream import (OnlineAnalyzer, SpooledTrace, TraceSpool,
-                          WindowVerdict, WindowVerdictLog)
+                          WindowVerdict, WindowVerdictLog, verify_segment)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -515,3 +519,85 @@ class TestOnsetBisection:
         rep = online.onset_report("dissimilarity")
         assert rep is not None
         assert 8 <= rep["onset_step"] <= 9
+
+
+def noisy_spool(directory, m=256):
+    """Two 4-step segments of a trace whose timings are float64 noise, so
+    each segment holds stored members beside deflated ones."""
+    _, _, base = drift_trace()
+    rids = base.region_ids
+    rng = np.random.default_rng(11)
+    shape = (8, 1, m, len(rids))
+    trace = RegionTrace(
+        region_ids=rids, n_processes=m, n_steps=8,
+        schema=base.schema, meta={"collector": "test"},
+        data={"wall_time": 1.0 + 0.005 * rng.standard_normal(shape),
+              "cpu_time": 0.9 + 0.005 * rng.standard_normal(shape),
+              "vmem_pressure": np.full(shape, 0.02)})
+    return trace, spool_up(trace, directory, chunk_steps=4)
+
+
+def stored_data_offset(path, member):
+    """File offset of the middle of a ``ZIP_STORED`` member's data."""
+    with zipfile.ZipFile(path) as z:
+        info = z.getinfo(member)
+    assert info.compress_type == zipfile.ZIP_STORED
+    with open(path, "rb") as f:
+        f.seek(info.header_offset)
+        local = f.read(30)
+    name_len, extra_len = struct.unpack("<HH", local[26:30])
+    return info.header_offset + 30 + name_len + extra_len \
+        + info.file_size // 2
+
+
+def flip_byte(path, offset):
+    with open(path, "r+b") as f:
+        f.seek(offset)
+        b = f.read(1)
+        f.seek(offset)
+        f.write(bytes([b[0] ^ 0xFF]))
+
+
+class TestSegmentEncoding:
+    def test_legacy_deflated_segment_still_loads(self, tmp_path):
+        """A segment written by the old writer, every member deflated by
+        ``np.savez_compressed`` under the same header, reads through
+        ``SpooledTrace.window`` to the same samples."""
+        trace, sp = noisy_spool(str(tmp_path / "sp"))
+        seg = sp.segment_records[1]
+        path = os.path.join(sp.directory, seg["file"])
+        with np.load(path) as z:
+            header = str(z["__header__"])
+        new = sp.segment(1)
+        with open(path, "wb") as f:
+            np.savez_compressed(f, __header__=header, **{
+                f"metric:{k}": new.data[k] for k in sorted(new.data)})
+        with zipfile.ZipFile(path) as z:
+            assert {i.compress_type for i in z.infolist()} \
+                == {zipfile.ZIP_DEFLATED}
+        with open(path, "rb") as f:
+            seg["sha256"] = hashlib.sha256(f.read()).hexdigest()
+        seg["bytes"] = os.path.getsize(path)
+        assert verify_segment(sp.directory, seg) is None
+        got = sp.window(2, 8)
+        for k in trace.data:
+            np.testing.assert_array_equal(got.data[k], trace.data[k][2:8])
+
+    @pytest.mark.parametrize("record", ["legacy", "sha256"])
+    def test_flip_in_stored_member_is_caught(self, tmp_path, record):
+        """A flipped byte inside a stored member's data: the manifest's
+        sha256 names it, and without one (a legacy record) zipfile's
+        CRC-32 does, as a load failure."""
+        _, sp = noisy_spool(str(tmp_path / "sp"))
+        seg = dict(sp.segment_records[0])
+        path = os.path.join(sp.directory, seg["file"])
+        flip_byte(path, stored_data_offset(path, "metric:wall_time.npy"))
+        if record == "legacy":
+            for key in ("sha256", "bytes"):
+                seg.pop(key)
+            assert verify_segment(sp.directory, seg) is not None
+            with pytest.raises(TraceFormatError) as e:
+                sp.segment(0)
+            assert e.value.member == "metric:wall_time"
+        else:
+            assert verify_segment(sp.directory, seg) == "sha256 mismatch"

@@ -7,6 +7,7 @@ three collector backends — and an offline analysis of a saved artifact
 equals the in-process verdict exactly.
 """
 import os
+import zipfile
 
 import numpy as np
 import pytest
@@ -349,3 +350,51 @@ class TestTrainBackend:
                 return
         assert not res.dissimilarity.exists, \
             res.verdict.dissimilarity_paths
+
+
+def mixed_trace(seed=0, m=512):
+    """A trace of the ST tree with noisy float64 timings and constant
+    rates, each member larger than the deflate probe."""
+    tree = st_region_tree()
+    rids = [r.region_id for r in tree.regions()]
+    rng = np.random.default_rng(seed)
+    shape = (2, 1, m, len(rids))
+    return RegionTrace(
+        region_ids=rids, n_processes=m, n_steps=2,
+        schema=schema_from_tree(tree), meta={"collector": "test"},
+        data={WALL_TIME: 1.0 + 0.005 * rng.standard_normal(shape),
+              CPU_TIME: 0.9 + 0.005 * rng.standard_normal(shape),
+              "vmem_pressure": np.full(shape, 0.02),
+              "comm_bytes": np.zeros(shape)})
+
+
+class TestMemberEncoding:
+    """Each member is deflated only where a probe of its bytes shrinks to
+    half; noisy timings are stored, constant metrics deflated."""
+
+    def test_noisy_stored_constant_deflated_bit_exact(self, tmp_path):
+        trace = mixed_trace()
+        path = str(tmp_path / "mixed.npz")
+        trace.save(path)
+        with zipfile.ZipFile(path) as z:
+            kinds = {i.filename: i.compress_type for i in z.infolist()}
+        assert kinds == {
+            "__header__.npy": zipfile.ZIP_DEFLATED,
+            f"metric:{CPU_TIME}.npy": zipfile.ZIP_STORED,
+            f"metric:{WALL_TIME}.npy": zipfile.ZIP_STORED,
+            "metric:comm_bytes.npy": zipfile.ZIP_DEFLATED,
+            "metric:vmem_pressure.npy": zipfile.ZIP_DEFLATED}
+        loaded = RegionTrace.load(path)
+        assert loaded.meta == trace.meta and loaded.schema == trace.schema
+        for k in trace.data:
+            assert loaded.data[k].tobytes() == trace.data[k].tobytes(), k
+        with np.load(path) as z:  # a plain .npz to any numpy reader
+            np.testing.assert_array_equal(z[f"metric:{WALL_TIME}"],
+                                          trace.data[WALL_TIME])
+
+    def test_same_trace_same_bytes(self, tmp_path):
+        a, b = str(tmp_path / "a.npz"), str(tmp_path / "b.npz")
+        mixed_trace(seed=3).save(a)
+        mixed_trace(seed=3).save(b)
+        with open(a, "rb") as fa, open(b, "rb") as fb:
+            assert fa.read() == fb.read()
