@@ -14,9 +14,9 @@ Runs, in this one process, on the chip JAX finds:
   (c) online   — OnlineAnalyzer over that spool with the Pallas distance
                  backend and with exact numpy: no degraded window, and
                  every window's verdict identical;
-  (d) analyzer — Algorithm 2 at m=65536 shards x n=128 regions
-                 (``benchmarks/analyzer_bench.algo2_workload``), Pallas vs
-                 numpy: identical results, the device lockstep path taken,
+  (d) analyzer — Algorithm 2 at m=65536 shards x n=128 regions (a flat
+                 tree, one planted straggler block), Pallas vs numpy:
+                 identical results, the device lockstep path taken,
                  and the distance kernel compiled to a TPU custom call;
   (e) corpus   — every synthetic corpus verdict under the Pallas backend
                  bit-identical to the committed VERDICTS_synthetic.json.
@@ -105,13 +105,28 @@ def online_phase(spool: str, window: int = 8):
     return len(docs["numpy"])
 
 
+def _algo2_workload(m: int, n: int):
+    """Flat n-region tree and an (m, n) near-balanced matrix whose first
+    m/8 shards straggle 6x in one region: Algorithm 2 walks every
+    depth-1 region and pins one CCR."""
+    import numpy as np
+
+    from repro.core import RegionTree
+
+    tree = RegionTree("bench")
+    for j in range(1, n + 1):
+        tree.add(f"cr{j}")
+    T = 1.0 + 0.05 * np.random.default_rng(0).random((m, n))
+    T[: max(1, m // 8), n // 3] *= 6.0
+    return tree, T, list(range(1, n + 1))
+
+
 def analyzer_phase(m: int, n: int):
     """(d) Algorithm 2 on the Pallas lane against the exact numpy lane;
     returns (pallas report, numpy report, pallas s, numpy s)."""
-    from benchmarks.analyzer_bench import algo2_workload
     from repro.core import find_dissimilarity_bottlenecks
 
-    tree, T, rids = algo2_workload(m, n)
+    tree, T, rids = _algo2_workload(m, n)
     t0 = time.perf_counter()
     fast = find_dissimilarity_bottlenecks(tree, T, rids, backend="pallas")
     t1 = time.perf_counter()

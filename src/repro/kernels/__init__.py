@@ -1,15 +1,5 @@
-"""Pallas TPU kernels for the perf-critical compute hot-spots:
-flash attention (causal/SWA), chunked WKV-6, fused RMSNorm, and the
-tiled pairwise-distance seed rows behind the analyzer's ``pallas``
-distance backend.
-Each kernel ships with a pure-jnp oracle in ref.py and a jit'd dispatch in
-ops.py (interpret mode on CPU, compiled on the TPU target).
+"""Pallas TPU kernels. One lives here: ``distance``, the tiled
+pairwise-distance seed rows behind the analyzer's ``pallas`` distance
+backend (compiled on a TPU, interpret mode elsewhere). Import it as
+``from repro.kernels import distance``.
 """
-from . import ops, ref
-from .distance import seed_rows as distance_seed_rows_kernel
-from .flash_attention import flash_attention as flash_attention_kernel
-from .rmsnorm import rmsnorm as rmsnorm_kernel
-from .rwkv6_scan import wkv6 as wkv6_kernel
-
-__all__ = ["ops", "ref", "distance_seed_rows_kernel",
-           "flash_attention_kernel", "rmsnorm_kernel", "wkv6_kernel"]

@@ -4,11 +4,10 @@ Parameters are nested dicts of arrays; every init function returns
 ``(params, axes)`` where ``axes`` mirrors the params tree with tuples of
 *logical* axis names consumed by ``repro.sharding.rules``.
 
-Attention comes in three flavours:
+Attention comes in two flavours:
   * naive (materialised scores) — small seqs / oracle,
   * chunked flash-style scan (online softmax) — the memory-bounded pure-JAX
-    path used in dry-runs and long sequences; same math as the Pallas kernel,
-  * Pallas TPU kernel (repro.kernels) — perf path on real hardware.
+    path used in dry-runs and long sequences.
 """
 from __future__ import annotations
 
@@ -190,7 +189,7 @@ def naive_attention(q, k, v, *, causal: bool, window: Optional[int],
                     q_positions, k_positions, softcap=None):
     """q (B,Q,H,dh), k/v (B,K,KV,dh) -> (B,Q,H,dh).  Materialises scores —
     for short sequences, single-token decode, and as the oracle for the
-    chunked/Pallas paths.  Operands stay in their storage dtype with f32
+    chunked path.  Operands stay in their storage dtype with f32
     MXU accumulation (``preferred_element_type``) — pre-casting a 32k-long
     KV cache to f32 would double its HBM/collective traffic (§Perf)."""
     B, Q, H, dh = q.shape
@@ -221,7 +220,6 @@ def chunked_attention(q, k, v, *, causal: bool, window: Optional[int],
     """Flash-style two-level blocked attention with online softmax.
 
     Memory is O(q_block × k_block) per step instead of O(Q × K); this is the
-    pure-JAX twin of the Pallas kernel (kernels/flash_attention.py) and the
     path the dry-run lowers for long sequences.
     """
     B, Q, H, dh = q.shape

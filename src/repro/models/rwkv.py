@@ -6,9 +6,9 @@ The WKV-6 recurrence per head (state S in R^{dh x dh}):
     S     = diag(w_t) S + k_t v_tᵀ
 
 with w_t = exp(-exp(w0 + lora(x_t))) the *data-dependent* decay (the Finch
-novelty vs RWKV-5).  Implemented as a lax.scan over time; the Pallas kernel
-(kernels/rwkv6_scan.py) provides the TPU chunked formulation with identical
-math (validated against :func:`wkv6_reference`).
+novelty vs RWKV-5).  :func:`wkv6_reference` is a lax.scan over time;
+:func:`wkv6_chunked` is the chunked matmul formulation of the same math,
+validated against it.
 """
 from __future__ import annotations
 
@@ -74,7 +74,7 @@ def wkv6_reference(r, k, v, w, u):
 
 
 def wkv6_chunked(r, k, v, w, u, S0, chunk: int = 64, unroll: bool = False):
-    """Chunked WKV-6 (same identity as kernels/rwkv6_scan.py) in pure jnp:
+    """Chunked WKV-6 (same identity as wkv6_reference) in pure jnp:
     MXU-matmul formulation, optionally fully unrolled over chunks so the
     dry-run cost probes count true FLOPs.  r,k,v,w: (B,T,H,dh); u: (H,dh);
     S0: (B,H,dh,dh).  Returns (out, S_final)."""
@@ -156,10 +156,9 @@ def rwkv_time_mix(params: Params, cfg: ModelConfig, x,
     S0 = state["S"] if state is not None else jnp.zeros((B, H, dh, dh), jnp.float32)
 
     if cfg.probe_unroll or T >= 512:
-        # Chunked MXU formulation (TPU production path twin; the Pallas
-        # kernel implements the same identity).  Longer sequences use a
-        # larger chunk: amortises state I/O and keeps the unrolled probe
-        # HLO bounded (<=128 chunk steps).
+        # Chunked MXU formulation (the TPU production path).  Longer
+        # sequences use a larger chunk: amortises state I/O and keeps the
+        # unrolled probe HLO bounded (<=128 chunk steps).
         chunk = 32 if T <= 8192 else 256
         y4, S = wkv6_chunked(r4, k4, v4, w4, u, S0, chunk=chunk,
                              unroll=cfg.probe_unroll)
